@@ -18,6 +18,7 @@ import jax
 import numpy as np
 
 from repro.config import get_config, smoke_variant
+from repro.core.tenancy import init_stacked
 from repro.models import build_model
 from repro.serving import EngineConfig, InferenceRequest, MultiTenantEngine
 
@@ -30,7 +31,7 @@ def bench_arch(arch: str, tenant_counts=(1, 2, 4, 8), steps: int = 12, csv_rows=
     print(f"\n--- {arch} (reduced) decode-step latency vs tenants ---")
     print(f"{'R':>3s} {'time_only ms':>14s} {'space_time ms':>14s} {'ratio':>7s}")
     for r in tenant_counts:
-        params = [m.init(jax.random.fold_in(key, t)) for t in range(r)]
+        params = init_stacked(m.init, key, r)
         lat = {}
         for mode in ("time_only", "space_time"):
             eng = MultiTenantEngine(

@@ -31,6 +31,7 @@ import numpy as np
 
 from repro.config import ScheduleConfig, get_config, smoke_variant
 from repro.core import DynamicSpaceTimeScheduler, GemmProblem, VirtualClock
+from repro.core.tenancy import init_stacked
 from repro.models import build_model
 from repro.serving import EngineConfig, InferenceRequest, MultiTenantEngine
 
@@ -92,7 +93,7 @@ def run(r: int = 5, steps: int = 16, csv_rows=None):
     m = build_model(cfg)
     key = jax.random.PRNGKey(0)
     rng = np.random.RandomState(0)
-    params = [m.init(jax.random.fold_in(key, t)) for t in range(r)]
+    params = init_stacked(m.init, key, r)
 
     for mode in ("time_only", "space_time"):
         eng = MultiTenantEngine(

@@ -16,6 +16,7 @@ import jax
 import numpy as np
 
 from repro.config import get_config, smoke_variant
+from repro.core.tenancy import init_stacked
 from repro.models import build_model
 from repro.serving import EngineConfig, InferenceRequest, MultiTenantEngine
 
@@ -35,9 +36,8 @@ def main() -> None:
     print(f"arch={args.arch} (reduced: {cfg.num_layers}L d={cfg.d_model}) "
           f"R={args.tenants} mode={args.mode}")
 
-    tenant_params = [model.init(jax.random.fold_in(key, t)) for t in range(args.tenants)]
     engine = MultiTenantEngine(
-        model, tenant_params,
+        model, init_stacked(model.init, key, args.tenants),
         EngineConfig(num_tenants=args.tenants, slots_per_tenant=2,
                      cache_len=96, mode=args.mode),
     )

@@ -391,11 +391,13 @@ class LiveRun:
     ``workload.arch`` picks the engine. The jax-free pseudo-archs
     ``"fake"`` (deterministic tokens) and ``"null"`` (no results — the
     sim-parity twin) serve CI and any CPU; every other name builds one
-    real jitted ``MultiTenantEngine`` per replica over SHARED
-    smoke-variant weights (N replicas space-multiplexing one host is the
-    paper's story told at the cluster layer). jax imports happen at
-    ``run()`` time so spec validation and sim-only workflows never pay
-    them.
+    real jitted ``MultiTenantEngine`` per replica, at the configuration's
+    published widths and dtype (``"<arch>-smoke"`` names the reduced CPU
+    variant). Replica ``i`` runs on device ``i % device_count``, and the
+    replicas on one device share one stacked weight tree. Each device's
+    ``device_kind`` must map to the hardware the cost model prices
+    (``launch.roofline.DEVICE_KINDS``). jax imports happen at ``run()``
+    time so spec validation and sim-only workflows never pay them.
 
     Wall-clock latencies are real, so live reports are NOT covered by
     the byte-identical determinism contract — routing decisions,
@@ -431,36 +433,55 @@ class LiveRun:
             return (lambda i: FakeEngine(i, max_new_tokens=w.max_new_tokens),
                     "fake", 32_000)
 
-        import dataclasses as _dc
-
         import jax
 
-        from repro.config import get_config, smoke_variant
+        from repro.config import get_config
+        from repro.core.tenancy import init_stacked
+        from repro.launch.roofline import check_device_hardware
         from repro.models import build_model
         from repro.serving import EngineConfig, MultiTenantEngine
         from repro.serving.fleet import EngineReplica
 
         spec = self.spec
-        cfg = _dc.replace(smoke_variant(get_config(w.arch)), dtype="float32")
+        devices = jax.devices()
+        n_rep = spec.fleet.replicas
+        hardware = spec.fleet.specs or (spec.cost_model.hardware,)
+        for i in range(n_rep):
+            check_device_hardware(devices[i % len(devices)].device_kind,
+                                  hardware[i % len(hardware)])
+        # the configuration at its published widths and its own dtype;
+        # the reduced CPU variant is asked for by name ("<arch>-smoke")
+        cfg = get_config(w.arch)
         model = build_model(cfg)
         key = jax.random.PRNGKey(w.seed)
-        params = [model.init(jax.random.fold_in(key, t))
-                  for t in range(w.tenants)]
         # the engine's contrast mode mirrors the cost-model strategy:
         # time_only gives each tenant its own bucket (sequential
         # dispatch), everything else rides the merged space-time path
         engine_mode = ("time_only" if spec.cost_model.strategy == "time_only"
                        else "space_time")
-        schedule = build_schedule(spec)
+        # one stacked weight tree per device, shared by every replica
+        # placed there; replica i runs on device i % device_count. The
+        # first device draws the weights, the others copy them (the same
+        # values, without compiling the draw once per device)
+        stacked_on = {}
 
         def factory(i: int) -> EngineReplica:
-            engine = MultiTenantEngine(model, params, EngineConfig(
+            device = devices[i % len(devices)]
+            if device not in stacked_on:
+                stacked_on[device] = (
+                    jax.device_put(next(iter(stacked_on.values())), device)
+                    if stacked_on else
+                    init_stacked(model.init, key, w.tenants, device=device))
+            engine = MultiTenantEngine(model, stacked_on[device], EngineConfig(
                 num_tenants=w.tenants,
                 slots_per_tenant=2,
                 cache_len=max(32, w.prompt_tokens + w.max_new_tokens + 8),
                 mode=engine_mode,
                 seed=w.seed + i,
-                schedule=schedule,
+                # admission and batching are the fleet pump's, under the
+                # spec's scheduler; the engine's own core keeps its greedy
+                # default (a feasibility policy there has no cost model)
+                schedule=None,
             ))
             return EngineReplica(engine, replica_id=i,
                                  max_new_tokens=w.max_new_tokens)

@@ -46,6 +46,7 @@ from repro.api.spec import (
     PROCESSES,
     SystemSpec,
 )
+from repro.launch.compile_cache import enable_compile_cache
 from repro.sim.costmodel import STRATEGIES
 from repro.sim.metrics import SCHEMA_VERSION, to_bench_json
 from repro.sim.router import ROUTERS
@@ -188,8 +189,8 @@ def cmd_serve(args) -> int:
         overrides["report_path"] = args.report
     if overrides:
         spec = dataclasses.replace(spec, **overrides)
-    run_server(spec)
-    return 0
+    server = run_server(spec)
+    return 0 if server.failure is None else 1
 
 
 # --------------------------------------------------------------------- trace
@@ -579,6 +580,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv: Optional[Sequence[str]] = None) -> int:
     args = build_parser().parse_args(argv)
+    enable_compile_cache()
     try:
         return args.func(args)
     except (TypeError, ValueError) as e:

@@ -31,12 +31,21 @@ def _ensure_loaded() -> None:
         _LOADED = True
 
 
+SMOKE_SUFFIX = "-smoke"
+
+
 def get_config(name: str) -> ModelConfig:
+    """The registered config at its published widths, or — for a name
+    ending in ``-smoke`` — ``smoke_variant`` of the base config. The
+    reduced model is reachable only by that explicit name."""
     _ensure_loaded()
+    if name.endswith(SMOKE_SUFFIX) and name not in _REGISTRY:
+        return smoke_variant(get_config(name[: -len(SMOKE_SUFFIX)]))
     try:
         return _REGISTRY[name]
     except KeyError:
-        raise KeyError(f"unknown arch {name!r}; options: {sorted(_REGISTRY)}")
+        raise KeyError(f"unknown arch {name!r}; options: {sorted(_REGISTRY)} "
+                       f"(append {SMOKE_SUFFIX!r} for the reduced CPU variant)")
 
 
 def list_configs() -> List[str]:
@@ -96,7 +105,7 @@ def smoke_variant(cfg: ModelConfig, *, num_layers: int = 2, d_model: int = 256) 
 
     return dataclasses.replace(
         cfg,
-        name=cfg.name + "-smoke",
+        name=cfg.name + SMOKE_SUFFIX,
         num_layers=num_layers,
         d_model=d_model,
         vocab_size=min(cfg.vocab_size, 1024),

@@ -16,7 +16,7 @@ while MPS hit the 16 GB wall at 18.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -27,6 +27,18 @@ Params = Any
 def stack_params(params_list: List[Params]) -> Params:
     """Stack R tenants' pytrees along a new leading axis."""
     return jax.tree.map(lambda *xs: jnp.stack(xs), *params_list)
+
+
+def init_stacked(init_fn: Callable[[jax.Array], Params], key: jax.Array,
+                 r: int, device=None) -> Params:
+    """R tenants' weights built directly in stacked form: tenant ``t``
+    draws from ``fold_in(key, t)``. No per-tenant tree ever exists, so
+    the device holds one copy of R tenants' weights (stacking a list
+    needs room for both). ``device`` places (and commits) the result."""
+    keys = jax.vmap(lambda t: jax.random.fold_in(key, t))(jnp.arange(r))
+    sharding = (jax.sharding.SingleDeviceSharding(device)
+                if device is not None else None)
+    return jax.jit(jax.vmap(init_fn), out_shardings=sharding)(keys)
 
 
 def unstack_params(stacked: Params, r: int) -> List[Params]:

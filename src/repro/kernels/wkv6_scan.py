@@ -34,7 +34,7 @@ def _wkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, state_ref, *, chunk: 
     def _init():
         state_ref[...] = jnp.zeros_like(state_ref)
 
-    u = u_ref[0]  # (N,)
+    u = u_ref[0, 0]  # (N,)
 
     def step(i, state):
         r = r_ref[0, i]      # (N,)
@@ -72,6 +72,12 @@ def wkv6_scan(
     """
     BH, T, N = r.shape
     V = v.shape[-1]
+    out_dtype = r.dtype
+    # the kernel reads one timestep row per loop step at a dynamic offset;
+    # Mosaic can only prove such an offset aligned for unpacked 32-bit
+    # rows, so 16-bit inputs are widened here (bf16 packs two rows per
+    # sublane and the row load is refused)
+    r, k, v, w, u = (a.astype(jnp.float32) for a in (r, k, v, w, u))
     chunk_ = min(chunk, T)
     Tp = pl.cdiv(T, chunk_) * chunk_
     if Tp != T:
@@ -89,11 +95,13 @@ def wkv6_scan(
             pl.BlockSpec((1, chunk_, N), lambda bh, tb: (bh, tb, 0)),
             pl.BlockSpec((1, chunk_, V), lambda bh, tb: (bh, tb, 0)),
             pl.BlockSpec((1, chunk_, N), lambda bh, tb: (bh, tb, 0)),
-            pl.BlockSpec((1, N), lambda bh, tb: (bh, 0)),
+            # (1, 1, N): the last two block dims equal the array's own,
+            # as the TPU lowering requires of a block narrower than (8, 128)
+            pl.BlockSpec((1, 1, N), lambda bh, tb: (bh, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, chunk_, V), lambda bh, tb: (bh, tb, 0)),
-        out_shape=jax.ShapeDtypeStruct((BH, Tp, V), r.dtype),
+        out_shape=jax.ShapeDtypeStruct((BH, Tp, V), jnp.float32),
         scratch_shapes=[pltpu.VMEM((N, V), jnp.float32)],
         interpret=interpret,
-    )(r, k, v, w, u)
-    return out[:, :T, :]
+    )(r, k, v, w, u.reshape(BH, 1, N))
+    return out[:, :T, :].astype(out_dtype)

@@ -110,6 +110,42 @@ HARDWARE_SPECS: Dict[str, HardwareSpec] = {
 }
 
 
+#: ``jax.Device.device_kind`` -> ``HARDWARE_SPECS`` name of that chip.
+#: The CPU backend maps to None: it runs the live path for functional
+#: tests only and has no roofline, so nothing it measures prices a chip.
+DEVICE_KINDS: Dict[str, Optional[str]] = {
+    "TPU v5 lite": "v5e",
+    "cpu": None,
+}
+
+
+def hardware_for_device(device_kind: str) -> Optional[str]:
+    """The ``HARDWARE_SPECS`` name of a device, from its ``device_kind``.
+
+    A kind missing from ``DEVICE_KINDS`` raises: pricing an unknown chip
+    with some other chip's roofline would pass a model off as the device.
+    """
+    try:
+        return DEVICE_KINDS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"unknown device kind {device_kind!r}: add it to "
+            f"repro.launch.roofline.DEVICE_KINDS with its HARDWARE_SPECS "
+            f"entry (known kinds: {sorted(DEVICE_KINDS)})") from None
+
+
+def check_device_hardware(device_kind: str, hardware) -> None:
+    """Raise unless ``hardware`` (a spec or name) describes the device
+    kind. CPU devices pass: they carry no roofline to disagree with."""
+    name = hardware_for_device(device_kind)
+    spec = resolve_spec(hardware)
+    if name is not None and HARDWARE_SPECS[name] != spec:
+        raise ValueError(
+            f"hardware spec {spec.name!r} does not describe the device "
+            f"{device_kind!r} (that is {name!r}); price this fleet with "
+            f"cost_model.hardware={name!r}")
+
+
 def resolve_spec(spec) -> HardwareSpec:
     """Accept a ``HardwareSpec`` or a ``HARDWARE_SPECS`` name.
 

@@ -8,7 +8,8 @@ deployed shape unchanged.
 
 Endpoints:
 
-    GET  /healthz     liveness + fleet shape (replicas, engine, router)
+    GET  /healthz     liveness + fleet shape (replicas, engine, router);
+                      503 once the fleet has failed
     POST /v1/predict  {"tenant_id": 0, "prompt": [1,2,3]} — routed,
                       admission-controlled, blocks until the cohort the
                       request merged into completes; 429 with the
@@ -20,6 +21,12 @@ pump thread wakes at ``min(next ripeness instant, poll_interval_s)`` and
 drives dispatch. Completion is signalled per-request through the pump's
 ``on_complete`` hook (a ``threading.Event`` on each workload), so a
 blocked handler costs one waiting thread, never a spin.
+
+A failure while executing fleet work (an engine raising, a device out of
+memory), in the pump thread or in a handler's submit, is kept, not
+swallowed: waiting predicts fail at once with 500,
+``/healthz`` answers 503 with the error, the server stops, and ``serve``
+exits non-zero.
 
 On SIGTERM/SIGINT (or server shutdown) the fleet drains and, when
 ``report_path`` is set, the final ``RunReport`` JSON lands there — the
@@ -33,14 +40,17 @@ from __future__ import annotations
 import argparse
 import json
 import signal
+import sys
 import threading
 import time
+import traceback
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Optional
 
 from repro.api.build import LiveRun, _augment_metrics, build_mix, build_recorder
 from repro.api.report import RunReport
 from repro.api.spec import ServeSpec
+from repro.launch.compile_cache import enable_compile_cache
 
 #: scheduler admission codes -> wire names (core.scheduler.admit_reason)
 ADMIT_REASONS = {0: "admitted", 1: "oversubscribed", 2: "cap",
@@ -63,11 +73,21 @@ class FleetServer:
         self.run = LiveRun(spec.system)
         self.recorder = build_recorder(spec.system)
         self.fleet, self.vocab = self.run.build_fleet(recorder=self.recorder)
-        self.mix = build_mix(spec.system.workload)
+        # a request's class is its tenant's first entry in the mix (the
+        # serving mix lists a prefill and a decode entry per tenant; a
+        # request starts with its prefill)
+        self.mix = []
+        for entry in build_mix(spec.system.workload):
+            if entry.tenant_id == len(self.mix):
+                self.mix.append(entry)
         self.lock = threading.Lock()
         self.started_s = time.perf_counter()
         self.requests = 0
         self.rejected = 0
+        # the pump thread's exception, formatted, once it has died
+        self.failure: Optional[str] = None
+        self._waiting = set()  # done events of predicts in flight
+        self._serving = False
         self._stop = threading.Event()
         self._pump_thread = threading.Thread(
             target=self._pump_loop, name="fleet-pump", daemon=True)
@@ -81,19 +101,34 @@ class FleetServer:
         spec = self.mix[tenant_id % len(self.mix)]
         done = threading.Event()
         t0 = time.perf_counter()
-        with self.lock:
-            self.requests += 1
-            w, replica_id, admitted, reason = self.fleet.submit_one(
-                spec, cost=spec.cost, payload=list(prompt or ()), done=done)
-        if not admitted:
+        try:
             with self.lock:
-                self.rejected += 1
+                if self.failure is not None:
+                    return self._failed()
+                self.requests += 1
+                # submit_one may run ripe work in this thread
+                w, replica_id, admitted, reason = self.fleet.submit_one(
+                    spec, cost=spec.cost, payload=list(prompt or ()),
+                    done=done)
+                if admitted:
+                    self._waiting.add(done)
+                else:
+                    self.rejected += 1
+        except Exception:
+            self._fail(traceback.format_exc())
+            return self._failed()
+        if not admitted:
             return {"status": 429,
                     "error": f"admission rejected: "
                              f"{ADMIT_REASONS.get(reason, reason)}",
                     "reason": ADMIT_REASONS.get(reason, str(reason)),
                     "replica": replica_id}
-        if not done.wait(self.spec.request_timeout_s):
+        finished = done.wait(self.spec.request_timeout_s)
+        with self.lock:
+            self._waiting.discard(done)
+        if w.result is None and self.failure is not None:
+            return self._failed()
+        if not finished:
             return {"status": 504,
                     "error": f"request did not complete within "
                              f"{self.spec.request_timeout_s:g}s",
@@ -103,6 +138,9 @@ class FleetServer:
                 "tokens": w.result,
                 "replica": replica_id,
                 "latency_s": time.perf_counter() - t0}
+
+    def _failed(self) -> dict:
+        return {"status": 500, "error": f"fleet failed: {self.failure}"}
 
     def report(self) -> RunReport:
         """Freeze the traffic served so far into a RunReport."""
@@ -120,6 +158,12 @@ class FleetServer:
 
     # ---------------------------------------------------------- lifecycle
     def _pump_loop(self) -> None:
+        try:
+            self._pump()
+        except Exception:
+            self._fail(traceback.format_exc())
+
+    def _pump(self) -> None:
         interval = self.spec.poll_interval_s
         while not self._stop.is_set():
             with self.lock:
@@ -129,10 +173,24 @@ class FleetServer:
             delay = interval if t_next is None else max(0.0, t_next - now)
             self._stop.wait(min(delay, interval))
 
+    def _fail(self, formatted: str) -> None:
+        """Keep a fleet exception (pump thread or a handler's submit),
+        fail every waiting predict, and stop a running server so
+        ``serve`` exits non-zero."""
+        print(f"fleet failed:\n{formatted}", file=sys.stderr, flush=True)
+        with self.lock:
+            self.failure = formatted.strip().splitlines()[-1]
+            waiting = list(self._waiting)
+        for done in waiting:
+            done.set()
+        if self._serving:
+            threading.Thread(target=self.httpd.shutdown, daemon=True).start()
+
     def start(self) -> None:
         self._pump_thread.start()
 
     def serve_forever(self) -> None:
+        self._serving = True
         self.start()
         try:
             self.httpd.serve_forever(poll_interval=0.2)
@@ -147,8 +205,10 @@ class FleetServer:
         if self._pump_thread.is_alive():
             self._pump_thread.join(timeout=5.0)
         with self.lock:
-            self.fleet._drain_wall_tail(
-                timeout_s=self.spec.request_timeout_s)
+            if self.failure is None:
+                # a failed pump's queues hold work its engine cannot run
+                self.fleet._drain_wall_tail(
+                    timeout_s=self.spec.request_timeout_s)
             self.run.save_calibration(self.fleet)
         if self.spec.report_path:
             self.report().save(self.spec.report_path)
@@ -172,6 +232,10 @@ def _make_handler(server: FleetServer):
 
         def do_GET(self) -> None:
             if self.path == "/healthz":
+                if server.failure is not None:
+                    self._send(503, {"status": "failed",
+                                     "error": server.failure})
+                    return
                 self._send(200, {
                     "status": "ok",
                     "replicas": len(server.fleet.active),
@@ -239,11 +303,12 @@ def main(argv=None) -> int:
     ap.add_argument("--port", type=int, default=None,
                     help="override serve.port")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     spec = ServeSpec.load(args.spec)
     if args.port is not None:
         spec = ServeSpec.from_dict({**spec.to_dict(), "port": args.port})
-    run_server(spec)
-    return 0
+    server = run_server(spec)
+    return 0 if server.failure is None else 1
 
 
 if __name__ == "__main__":

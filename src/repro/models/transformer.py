@@ -210,7 +210,8 @@ class Model:
                 keys[3], BlockKind.HYBRID_SHARED_ATTN, cfg, dtype
             )
 
-        # stacked per-unit params
+        # stacked per-unit params, drawn by one vmapped block init (the
+        # same values as a loop over reps, in a program 1/reps the size)
         unit_keys = jax.random.split(keys[4], max(reps, 1) * len(unit)).reshape(
             max(reps, 1), len(unit), -1
         )
@@ -218,10 +219,9 @@ class Model:
         for p, (kind, _) in enumerate(unit):
             if kind == BlockKind.HYBRID_SHARED_ATTN:
                 continue  # shared, not stacked
-            per_unit = [
-                _block_init(unit_keys[r, p], kind, cfg, dtype) for r in range(reps)
-            ]
-            unit_params[f"pos{p}"] = jax.tree.map(lambda *xs: jnp.stack(xs), *per_unit)
+            unit_params[f"pos{p}"] = jax.vmap(
+                lambda k, _kind=kind: _block_init(k, _kind, cfg, dtype)
+            )(unit_keys[:reps, p])
         params["unit"] = unit_params
 
         # remainder layers, unrolled

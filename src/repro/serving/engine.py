@@ -23,6 +23,7 @@ Used by benchmarks/fig3_latency.py and fig4_predictability.py.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Any, Dict, List, Optional
 
@@ -33,11 +34,56 @@ import numpy as np
 from repro.config import ScheduleConfig
 from repro.core.scheduler import DynamicSpaceTimeScheduler
 from repro.core.workload import Workload
-from repro.core.tenancy import stack_params
 from repro.models import Model
 from repro.serving.kv_cache import SlotManager
 from repro.serving.request import InferenceRequest, RequestState
 from repro.serving.sampling import SamplingParams, sample
+
+
+def _tenant(stacked: Any, t) -> Any:
+    """Tenant ``t``'s slice of a stacked tree (``t`` may be traced)."""
+    return jax.tree.map(
+        lambda x: jax.lax.dynamic_index_in_dim(x, t, keepdims=False), stacked)
+
+
+@dataclasses.dataclass(frozen=True)
+class EnginePrograms:
+    """The engine's jitted programs over stacked params and caches."""
+
+    decode_all: Any     # (params, tokens, caches, lengths): every tenant
+    decode_one: Any     # (params, caches, t, tokens, lengths): tenant t
+    prefill: Any        # (params, t, tokens): tenant t, fresh sequence
+    prefill_cont: Any   # (params, t, tokens, caches, start): next chunk
+
+
+@functools.lru_cache(maxsize=16)
+def engine_programs(model: Model, cache_len: int) -> EnginePrograms:
+    """Jitted programs for one (model, cache length), shared by every
+    engine that serves it, so replicas on one device compile once.
+
+    Per-tenant programs take the whole stacked tree plus a traced tenant
+    index and select inside the program: one compile serves every
+    tenant, and no eager per-call copy of a tenant's weights is made.
+    """
+
+    def decode_all(params, tokens, caches, lengths):
+        return jax.vmap(model.forward_decode)(params, tokens, caches, lengths)
+
+    def decode_one(params, caches, t, tokens, lengths):
+        return model.forward_decode(
+            _tenant(params, t), tokens, _tenant(caches, t), lengths)
+
+    def prefill(params, t, tokens):
+        return model.forward_prefill(
+            _tenant(params, t), tokens, cache_len=cache_len)
+
+    def prefill_cont(params, t, tokens, caches, start):
+        return model.forward_prefill(
+            _tenant(params, t), tokens, cache_len=cache_len,
+            caches=caches, start=start)
+
+    return EnginePrograms(*(jax.jit(f) for f in (
+        decode_all, decode_one, prefill, prefill_cont)))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -60,18 +106,32 @@ class EngineConfig:
 
 
 class MultiTenantEngine:
-    def __init__(self, model: Model, tenant_params: List[Any], config: EngineConfig):
-        assert len(tenant_params) == config.num_tenants
+    """R tenants' decode loop over ONE stacked weight tree.
+
+    ``stacked_params`` carries every tenant's weights along a leading
+    tenant axis (``core.tenancy.stack_params`` / ``init_stacked``); the
+    engine holds no per-tenant copy, so engines on one device can share
+    the tree. The stacked caches are placed on the device that holds the
+    weights, which is what pins a fleet replica to its chip.
+    """
+
+    def __init__(self, model: Model, stacked_params: Any, config: EngineConfig):
+        lead = {x.shape[0] for x in jax.tree.leaves(stacked_params)}
+        if lead != {config.num_tenants}:
+            raise ValueError(
+                f"stacked params lead with {sorted(lead)} tenants, "
+                f"config has {config.num_tenants}")
         self.model = model
         self.cfg = config
-        self.stacked_params = stack_params(tenant_params)
-        self._tenant_params = tenant_params
+        self.stacked_params = stacked_params
 
         R, B = config.num_tenants, config.slots_per_tenant
         single = model.init_caches(B, config.cache_len)
-        self.caches = jax.tree.map(
-            lambda x: jnp.broadcast_to(x[None], (R,) + x.shape).copy(), single
-        )
+        device = next(iter(jax.tree.leaves(stacked_params)[0].devices()))
+        self.caches = jax.device_put(
+            jax.tree.map(
+                lambda x: jnp.broadcast_to(x[None], (R,) + x.shape), single),
+            device)
         self.slots = SlotManager(R, B)
 
         # the unified scheduling core: prefill + decode cohorts flow
@@ -99,29 +159,11 @@ class MultiTenantEngine:
         self._pending_caches: Dict[int, Any] = {}      # time_only per-tenant updates
         self._pending_logits: Dict[int, jax.Array] = {}
 
-        # ---- jitted programs -------------------------------------------------
-        def _decode_all(params, tokens, caches, lengths):
-            return jax.vmap(model.forward_decode)(params, tokens, caches, lengths)
-
-        self._decode_all = jax.jit(_decode_all)
-
-        def _decode_one(params, tokens, caches, lengths):
-            return model.forward_decode(params, tokens, caches, lengths)
-
-        self._decode_one = jax.jit(_decode_one)
-
-        def _prefill(params, tokens):
-            return model.forward_prefill(params, tokens, cache_len=config.cache_len)
-
-        self._prefill = jax.jit(_prefill)
-
-        def _prefill_cont(params, tokens, caches, start):
-            return model.forward_prefill(
-                params, tokens, cache_len=config.cache_len,
-                caches=caches, start=start,
-            )
-
-        self._prefill_cont = jax.jit(_prefill_cont)
+        progs = engine_programs(model, config.cache_len)
+        self._decode_all = progs.decode_all
+        self._decode_one = progs.decode_one
+        self._prefill = progs.prefill
+        self._prefill_cont = progs.prefill_cont
 
     # ---------------------------------------------------------------- monitor
     @property
@@ -182,9 +224,8 @@ class MultiTenantEngine:
         outs = []
         for wl in batch:
             req: InferenceRequest = wl.payload
-            params_t = jax.tree.map(lambda x: x[req.tenant_id], self.stacked_params)
             tokens = jnp.asarray(np.asarray(req.prompt, np.int32))[None, :]
-            logits, cache = self._run_prefill(params_t, tokens)
+            logits, cache = self._run_prefill(req.tenant_id, tokens)
             tok = int(jnp.argmax(logits[0]))
             req.generated.append(tok)
             req.first_token_time = time.perf_counter()
@@ -197,18 +238,19 @@ class MultiTenantEngine:
             outs.append(tok)
         return outs
 
-    def _run_prefill(self, params_t, tokens):
+    def _run_prefill(self, tenant: int, tokens):
         """Whole-prompt or chunked prefill (bounded compile count)."""
         C = self.cfg.prefill_chunk
         S = tokens.shape[1]
+        params, t = self.stacked_params, np.int32(tenant)
         if C <= 0 or S <= C:
-            return self._prefill(params_t, tokens)
-        logits, cache = self._prefill(params_t, tokens[:, :C])
+            return self._prefill(params, t, tokens)
+        logits, cache = self._prefill(params, t, tokens[:, :C])
         pos = C
         while pos < S:
             n = min(C, S - pos)  # ragged tail compiles once per tail length
             logits, cache = self._prefill_cont(
-                params_t, tokens[:, pos:pos + n], cache, jnp.int32(pos))
+                params, t, tokens[:, pos:pos + n], cache, jnp.int32(pos))
             pos += n
         return logits, cache
 
@@ -268,11 +310,10 @@ class MultiTenantEngine:
         outs = []
         for wl in batch:
             t = wl.payload
-            params_t = jax.tree.map(lambda x: x[t], self.stacked_params)
-            caches_t = jax.tree.map(lambda x: x[t], self.caches)
             tokens_t = jnp.asarray(self.last_token[t])
             lengths_t = jnp.asarray(self.slots.lengths(t), jnp.int32)
-            lg, nc = self._decode_one(params_t, tokens_t, caches_t, lengths_t)
+            lg, nc = self._decode_one(self.stacked_params, self.caches,
+                                      np.int32(t), tokens_t, lengths_t)
             lg = jax.block_until_ready(lg)
             self._pending_caches[t] = nc
             self._pending_logits[t] = lg

@@ -75,3 +75,17 @@ def test_unknown_arch_raises():
 def test_all_archs_have_sources():
     for a in list_configs():
         assert get_config(a).source, a
+
+
+@pytest.mark.parametrize("arch", ASSIGNED_ARCHS)
+def test_smoke_name_resolves_to_smoke_variant(arch):
+    # the reduced model is reachable only by its explicit "-smoke" name;
+    # the plain name stays the published configuration
+    assert get_config(arch + "-smoke") == smoke_variant(get_config(arch))
+    assert get_config(arch).name == arch
+    assert arch + "-smoke" not in list_configs()
+
+
+def test_smoke_name_of_unknown_arch_raises():
+    with pytest.raises(KeyError, match="nope"):
+        get_config("nope-smoke")

@@ -85,3 +85,61 @@ def test_analytic_collectives_policies():
     assert repl["total"] == 0.0  # replicated decode: no collectives
     pod2 = analytic_collectives(cfg, train, policy="fsdp", tp_acts=True, pods=2)
     assert pod2["pod_ar"] > 0 and pod2["total"] > fsdp["total"]
+
+
+class TestCompileCache:
+    """The persistent compile cache: JAX_COMPILATION_CACHE_DIR wins where
+    it is set; otherwise one fixed directory inside the checkout."""
+
+    @pytest.fixture(autouse=True)
+    def _restore(self):
+        was = jax.config.jax_compilation_cache_dir
+        yield
+        jax.config.update("jax_compilation_cache_dir", was)
+
+    def test_env_var_honoured(self, monkeypatch, tmp_path):
+        from repro.launch import compile_cache
+
+        monkeypatch.setenv(compile_cache.ENV_VAR, str(tmp_path))
+        before = jax.config.jax_compilation_cache_dir
+        assert compile_cache.enable_compile_cache() == str(tmp_path)
+        # JAX reads the variable itself; the code sets no other directory
+        assert jax.config.jax_compilation_cache_dir == before
+
+    def test_default_is_fixed_path_in_checkout(self, monkeypatch):
+        import pathlib
+
+        from repro.launch import compile_cache
+
+        monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+        repo = pathlib.Path(__file__).resolve().parents[1]
+        want = str(repo / ".jax_cache")
+        assert compile_cache.enable_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+        assert compile_cache.enable_compile_cache() == want  # every run
+
+
+class TestDeviceKinds:
+    def test_v5e_kind_maps_to_v5e(self):
+        from repro.launch.roofline import check_device_hardware, hardware_for_device
+
+        assert hardware_for_device("TPU v5 lite") == "v5e"
+        check_device_hardware("TPU v5 lite", "v5e")
+
+    def test_unknown_kind_raises(self):
+        from repro.launch.roofline import hardware_for_device
+
+        with pytest.raises(ValueError, match="unknown device kind"):
+            hardware_for_device("TPU v9 imaginary")
+
+    def test_spec_that_does_not_match_device_raises(self):
+        from repro.launch.roofline import check_device_hardware
+
+        with pytest.raises(ValueError, match="does not describe"):
+            check_device_hardware("TPU v5 lite", "v5e_half")
+
+    def test_cpu_has_no_roofline(self):
+        from repro.launch.roofline import check_device_hardware, hardware_for_device
+
+        assert hardware_for_device("cpu") is None
+        check_device_hardware("cpu", "v5e")  # functional runs only

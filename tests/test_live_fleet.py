@@ -192,8 +192,26 @@ class TestLiveSpec:
     def test_real_engine_smoke(self):
         spec = self._spec(workload={
             "mix": "sgemm", "tenants": 2, "events": 4, "seed": 0,
-            "rate_hz": 50.0, "arch": "stablelm-1.6b", "prompt_tokens": 4,
+            "rate_hz": 50.0, "arch": "stablelm-1.6b-smoke", "prompt_tokens": 4,
             "max_new_tokens": 4})
         rep = spec.build().run()
         assert rep.metrics["engine"] == "jax"
         assert rep.metrics["scheduler"]["completed"] == 4
+
+    def test_replicas_on_one_device_share_stacked_weights(self):
+        import jax
+
+        spec = self._spec(workload={
+            "mix": "sgemm", "tenants": 2, "events": 4, "seed": 0,
+            "rate_hz": 50.0, "arch": "stablelm-1.6b-smoke"})
+        factory, name, vocab = spec.build().build_engine_factory()
+        assert (name, vocab) == ("jax", 1024)
+        a, b = factory(0).engine, factory(1).engine
+        assert len(jax.devices()) == 1
+        # one stacked tree for both replicas, weights and caches on the
+        # device the replica was placed on
+        assert a.stacked_params is b.stacked_params
+        device = jax.devices()[0]
+        for x in jax.tree.leaves((a.stacked_params, a.caches)):
+            assert x.devices() == {device}
+            assert x.shape[0] == 2

@@ -153,6 +153,76 @@ class TestShutdown:
         srv.shutdown()
 
 
+class TestPumpFailure:
+    """A fleet failure (here an injected engine fault) is kept: waiting
+    predicts fail at once, /healthz turns 503, and serve exits 1."""
+
+    @pytest.fixture()
+    def failing_engine(self, monkeypatch):
+        from repro.serving.fleet import FakeEngine
+
+        def execute(self, batch):
+            raise RuntimeError("injected engine fault")
+
+        monkeypatch.setattr(FakeEngine, "execute", execute)
+
+    def test_predict_fails_fast_and_healthz_503(self, failing_engine):
+        import time
+
+        srv = FleetServer(_serve_spec())
+        srv.start()
+        threading.Thread(target=srv.httpd.serve_forever, daemon=True).start()
+        try:
+            t0 = time.perf_counter()
+            with pytest.raises(urllib.error.HTTPError) as e:
+                _predict(srv, 0, [1, 2])
+            assert e.value.code == 500
+            assert "injected engine fault" in json.loads(e.value.read())["error"]
+            assert time.perf_counter() - t0 < 5.0  # not the 10 s timeout
+            with pytest.raises(urllib.error.HTTPError) as e:
+                _get(srv, "/healthz")
+            assert e.value.code == 503
+            assert "injected" in json.loads(e.value.read())["error"]
+        finally:
+            srv.httpd.shutdown()
+            srv.shutdown()
+
+    def test_serve_main_exits_nonzero(self, failing_engine, tmp_path):
+        import socket
+        import types
+
+        import jax
+
+        from repro.launch.serve import main
+
+        with socket.socket() as s:
+            s.bind(("127.0.0.1", 0))
+            srv = types.SimpleNamespace(port=s.getsockname()[1])
+        path = tmp_path / "serve.json"
+        path.write_text(_serve_spec().to_json())
+        rc = []
+        cache_dir = jax.config.jax_compilation_cache_dir
+        t = threading.Thread(
+            target=lambda: rc.append(main(["--spec", str(path),
+                                           "--port", str(srv.port)])),
+            daemon=True)
+        t.start()
+        try:
+            for _ in range(200):  # wait for the listener
+                try:
+                    _get(srv, "/healthz")
+                    break
+                except (urllib.error.URLError, ConnectionError):
+                    t.join(0.05)
+            with pytest.raises(urllib.error.HTTPError) as e:
+                _predict(srv, 0, [1])
+            assert e.value.code == 500
+            t.join(timeout=10)
+            assert rc == [1]
+        finally:
+            jax.config.update("jax_compilation_cache_dir", cache_dir)
+
+
 class TestServeSpec:
     def test_round_trip(self):
         spec = _serve_spec()

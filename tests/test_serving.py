@@ -10,6 +10,7 @@ import numpy as np
 import pytest
 
 from repro.config import get_config, smoke_variant
+from repro.core.tenancy import stack_params
 from repro.models import build_model
 from repro.serving import EngineConfig, InferenceRequest, MultiTenantEngine
 
@@ -20,7 +21,7 @@ def _setup(arch, R=3, mode="space_time", slots=2, cache_len=64):
     key = jax.random.PRNGKey(0)
     tenant_params = [m.init(jax.random.fold_in(key, t)) for t in range(R)]
     eng = MultiTenantEngine(
-        m, tenant_params,
+        m, stack_params(tenant_params),
         EngineConfig(num_tenants=R, slots_per_tenant=slots, cache_len=cache_len, mode=mode),
     )
     return cfg, m, tenant_params, eng

@@ -20,6 +20,7 @@ from repro.core import (
     Workload,
 )
 from repro.core.policy import FixedWindowPolicy, SLOAdaptiveWindowPolicy
+from repro.core.tenancy import stack_params
 from repro.core.superkernel import SuperKernelCache
 from repro.kernels import ref
 from repro.models import build_model
@@ -199,7 +200,7 @@ def _setup_engine(mode, R=2, slots=1, cache_len=32):
     m = build_model(cfg)
     key = jax.random.PRNGKey(0)
     params = [m.init(jax.random.fold_in(key, t)) for t in range(R)]
-    eng = MultiTenantEngine(m, params, EngineConfig(
+    eng = MultiTenantEngine(m, stack_params(params), EngineConfig(
         num_tenants=R, slots_per_tenant=slots, cache_len=cache_len, mode=mode))
     return cfg, eng
 
@@ -259,7 +260,7 @@ class TestEngineThroughScheduler:
             ("default", None),
             ("split", ScheduleConfig(batching_window_s=0.0, max_superkernel_size=1)),
         ):
-            eng = MultiTenantEngine(m, params, EngineConfig(
+            eng = MultiTenantEngine(m, stack_params(params), EngineConfig(
                 num_tenants=2, slots_per_tenant=1, cache_len=32,
                 mode="space_time", schedule=schedule))
             for t, p in enumerate(prompts):
@@ -274,7 +275,7 @@ class TestEngineThroughScheduler:
         and retry on a later step — no request may be silently dropped."""
         cfg, eng_unused = _setup_engine("space_time")  # build model/config once
         m = eng_unused.model
-        params = eng_unused._tenant_params
+        params = eng_unused.stacked_params
         eng = MultiTenantEngine(m, params, EngineConfig(
             num_tenants=2, slots_per_tenant=2, cache_len=32, mode="space_time",
             schedule=ScheduleConfig(batching_window_s=0.0,
