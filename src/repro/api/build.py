@@ -416,11 +416,13 @@ class LiveRun:
         self.engine_name = None
         self.wall_s = 0.0
 
-    def build_engine_factory(self):
+    def build_engine_factory(self, recorder=None):
         """``(engine_factory, engine_name, vocab)`` for ``workload.arch``.
 
         Only the real-arch branch imports jax; "fake"/"null" stay pure
-        python so the live fleet path runs anywhere.
+        python so the live fleet path runs anywhere. With a ``recorder``
+        each real engine records its decode steps into its replica's
+        shard.
         """
         w = self.spec.workload
         if w.arch == "null":
@@ -483,6 +485,8 @@ class LiveRun:
                 # default (a feasibility policy there has no cost model)
                 schedule=None,
             ))
+            if recorder is not None:
+                engine.recorder = recorder.shard(i)
             return EngineReplica(engine, replica_id=i,
                                  max_new_tokens=w.max_new_tokens)
 
@@ -495,7 +499,7 @@ class LiveRun:
 
         spec = self.spec
         fleet_spec, cost = spec.fleet, spec.cost_model
-        factory, engine_name, vocab = self.build_engine_factory()
+        factory, engine_name, vocab = self.build_engine_factory(recorder)
         self.engine_name = engine_name
         calibration = build_fleet_calibration(cost)
         fleet = LiveFleet(

@@ -35,6 +35,10 @@ class Clock:
         """Jump forward to an absolute time (never backwards)."""
         raise NotImplementedError
 
+    def ahead_of(self, t_s: float) -> float:
+        """Seconds this timeline has run ahead of the instant ``t_s``."""
+        return max(0.0, self.now() - t_s)
+
 
 class WallClock(Clock):
     """Real host time. The production default."""
@@ -52,6 +56,11 @@ class WallClock(Clock):
         # calls advance_to unconditionally, and only virtual timelines
         # actually move under it.
         pass
+
+    def ahead_of(self, t_s: float) -> float:
+        # one real time for every replica: a wall clock is never ahead of
+        # an instant read from it, only later than it
+        return 0.0
 
 
 class VirtualClock(Clock):

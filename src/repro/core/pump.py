@@ -378,7 +378,7 @@ class PumpCore:
         """Estimated seconds until this replica would run dry: residual
         busy time (its clock ahead of global ``now``) plus the estimated
         cost of everything still queued."""
-        return max(0.0, self.clock.now() - now) + self.pending_est_s
+        return self.clock.ahead_of(now) + self.pending_est_s
 
     def estimate_item_s(self, w) -> float:
         """Estimated seconds this item adds to THIS replica.

@@ -22,6 +22,11 @@ drives dispatch. Completion is signalled per-request through the pump's
 ``on_complete`` hook (a ``threading.Event`` on each workload), so a
 blocked handler costs one waiting thread, never a spin.
 
+With the flight recorder on, each answered predict writes one row of
+request ``STAGES`` (``repro.obs.recorder``) into its replica's shard,
+under an id the front door assigns: ``front.receive`` is stamped before
+the fleet lock, so the row shows how long a request waited for it.
+
 A failure while executing fleet work (an engine raising, a device out of
 memory), in the pump thread or in a handler's submit, is kept, not
 swallowed: waiting predicts fail at once with 500,
@@ -38,6 +43,7 @@ serve-smoke CI contract.
 from __future__ import annotations
 
 import argparse
+import itertools
 import json
 import signal
 import sys
@@ -81,6 +87,9 @@ class FleetServer:
             if entry.tenant_id == len(self.mix):
                 self.mix.append(entry)
         self.lock = threading.Lock()
+        # request ids, and the lock the stage rows are written under
+        self._ids = itertools.count()
+        self._stage_lock = threading.Lock()
         self.started_s = time.perf_counter()
         self.requests = 0
         self.rejected = 0
@@ -101,6 +110,8 @@ class FleetServer:
         spec = self.mix[tenant_id % len(self.mix)]
         done = threading.Event()
         t0 = time.perf_counter()
+        rec = self.recorder
+        request_id = None if rec is None else next(self._ids)
         try:
             with self.lock:
                 if self.failure is not None:
@@ -109,7 +120,7 @@ class FleetServer:
                 # submit_one may run ripe work in this thread
                 w, replica_id, admitted, reason = self.fleet.submit_one(
                     spec, cost=spec.cost, payload=list(prompt or ()),
-                    done=done)
+                    done=done, request_id=request_id)
                 if admitted:
                     self._waiting.add(done)
                 else:
@@ -133,11 +144,26 @@ class FleetServer:
                     "error": f"request did not complete within "
                              f"{self.spec.request_timeout_s:g}s",
                     "replica": replica_id}
-        return {"status": 200,
-                "tenant_id": spec.tenant_id,
-                "tokens": w.result,
-                "replica": replica_id,
-                "latency_s": time.perf_counter() - t0}
+        t1 = time.perf_counter()
+        out = {"status": 200,
+               "tenant_id": spec.tenant_id,
+               "tokens": w.result,
+               "replica": replica_id,
+               "latency_s": t1 - t0}
+        if rec is not None:
+            self._record_stages(rec.shard(replica_id), w, t0, t1)
+        return out
+
+    def _record_stages(self, shard, w, t_receive: float,
+                       t_respond: float) -> None:
+        """The answered request's row of ``STAGES``; an engine that
+        stamps nothing (fake, null) leaves its stages NaN."""
+        nan = float("nan")
+        dispatch, slot, first, last = w.stamps or (nan, nan, nan, nan)
+        with self._stage_lock:
+            shard.record_stages(w.request_id, w.tenant_id, (
+                t_receive, w.arrival_time, dispatch, slot, first, last,
+                w.completion_time, t_respond))
 
     def _failed(self) -> dict:
         return {"status": 500, "error": f"fleet failed: {self.failure}"}

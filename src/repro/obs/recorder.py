@@ -24,6 +24,13 @@ Event families and where they are emitted:
                           per-replica price vector that justified it
                           (``route_price_vector``)
     scale event           the autoscale timeline, verbatim
+    request stages        live serving only (``launch.serve``), one row
+                          per served request written once it is answered:
+                          the ``STAGES`` instants from the front door's
+                          receipt to its response, keyed by request id
+    decode step           live engines only (``MultiTenantEngine.step``),
+                          one row per decode step: the ``STEP_STAMPS``
+                          host instants, tokens produced, tenants run
 
 Determinism: shards are keyed by replica id and filled in each replica's
 own event order, fleet-level routes in global arrival order — both pure
@@ -39,6 +46,28 @@ from __future__ import annotations
 
 from array import array
 from typing import Dict, Hashable, List, Optional, Sequence, Tuple
+
+
+#: a served request's instants, in the order they happen: the front door
+#: has the parsed body; the fleet stamps the arrival (under its lock); the
+#: dispatch carrying it starts; the engine acquires its slot; its first
+#: and last tokens are on the host; the dispatch ends (the cohort has
+#: drained); the front door has built the response
+STAGES = ("front.receive", "fleet.arrive", "fleet.dispatch", "engine.slot",
+          "engine.first_token", "engine.last_token", "fleet.complete",
+          "front.respond")
+#: a decode step's host instants: the step starts (after admission);
+#: ``decode_all`` has returned to Python; ``block_until_ready`` has
+#: returned; argmax and ``np.asarray`` are done; the per-slot bookkeeping
+#: is done
+STEP_STAMPS = ("step.begin", "step.enqueue", "step.ready", "step.sampled",
+               "step.end")
+#: the shard columns of ``STAGES`` and ``STEP_STAMPS``, in their order
+STAGE_COLUMNS = ("_stg_receive", "_stg_arrive", "_stg_dispatch",
+                 "_stg_slot", "_stg_first", "_stg_last", "_stg_complete",
+                 "_stg_respond")
+STEP_COLUMNS = ("_stp_begin", "_stp_enqueue", "_stp_ready", "_stp_sampled",
+                "_stp_end")
 
 
 def bucket_label(bucket) -> str:
@@ -90,6 +119,26 @@ class ReplicaShard:
         self._req_tenant = array("l")
         self._req_slo = array("d")
         self._req_bucket = array("l")
+        # request stages (live serving; one row per answered request, one
+        # column per entry of ``STAGES``, NaN where no stage was stamped)
+        self._stg_id = array("q")
+        self._stg_tenant = array("l")
+        self._stg_receive = array("d")
+        self._stg_arrive = array("d")
+        self._stg_dispatch = array("d")
+        self._stg_slot = array("d")
+        self._stg_first = array("d")
+        self._stg_last = array("d")
+        self._stg_complete = array("d")
+        self._stg_respond = array("d")
+        # decode steps (live engines; one column per ``STEP_STAMPS``)
+        self._stp_begin = array("d")
+        self._stp_enqueue = array("d")
+        self._stp_ready = array("d")
+        self._stp_sampled = array("d")
+        self._stp_end = array("d")
+        self._stp_tokens = array("l")
+        self._stp_tenants = array("l")
 
     # -------------------------------------------------------------- intern
     def _intern(self, bucket) -> int:
@@ -143,6 +192,23 @@ class ReplicaShard:
             self._req_slo.extend([w.slo_s for w in batch])
             self._req_bucket.extend(bis)
 
+    def record_stages(self, request_id: int, tenant_id: int,
+                      stamps: Sequence[float]) -> None:
+        """One answered request: its ``STAGES`` instants, in order."""
+        self._stg_id.append(request_id)
+        self._stg_tenant.append(tenant_id)
+        for col, t in zip(STAGE_COLUMNS, stamps):
+            getattr(self, col).append(t)
+
+    def record_step(self, stamps: Sequence[float], tokens: int,
+                    tenants: int) -> None:
+        """One decode step: its ``STEP_STAMPS`` instants, the tokens it
+        produced and the number of tenants it ran."""
+        for col, t in zip(STEP_COLUMNS, stamps):
+            getattr(self, col).append(t)
+        self._stp_tokens.append(tokens)
+        self._stp_tenants.append(tenants)
+
     # -------------------------------------------------------------- sizing
     @property
     def n_arrivals(self) -> int:
@@ -160,6 +226,14 @@ class ReplicaShard:
     def n_preemptions(self) -> int:
         return len(self._pre_t)
 
+    @property
+    def n_stages(self) -> int:
+        return len(self._stg_id)
+
+    @property
+    def n_steps(self) -> int:
+        return len(self._stp_begin)
+
     # ---------------------------------------------------- worker transport
     _COLUMNS = ("_arr_t", "_arr_tenant", "_arr_bucket", "_arr_admitted",
                 "_arr_reason",
@@ -167,7 +241,8 @@ class ReplicaShard:
                 "_dsp_cold", "_req_t0", "_req_t1", "_req_tenant",
                 "_req_slo", "_req_bucket",
                 "_pre_t", "_pre_tenant", "_pre_bucket", "_pre_est",
-                "_pre_victims")
+                "_pre_victims", "_stg_id", "_stg_tenant", *STAGE_COLUMNS,
+                *STEP_COLUMNS, "_stp_tokens", "_stp_tenants")
 
     def payload(self) -> Dict:
         """Compact picklable form (arrays + label table) for shipping a
@@ -251,7 +326,7 @@ class FlightRecorder:
             + len(self.partition_events)
         for s in self.shards.values():
             n += (s.n_arrivals + s.n_dispatches + s.n_requests
-                  + s.n_preemptions)
+                  + s.n_preemptions + s.n_stages + s.n_steps)
         return n
 
 

@@ -6,11 +6,15 @@ Perfetto (ui.perfetto.dev) and ``chrome://tracing`` load directly:
     pid 1  "replicas"   one thread per replica — dispatch spans ("X"),
                         named by bucket, with batch size R, cold/warm,
                         and strategy in args: the space-time packing
-                        picture, cold starts visibly longer
+                        picture, cold starts visibly longer; a live
+                        engine's decode steps as one span per host phase,
+                        named by the ``STEP_STAMPS`` entry that ends it
     pid 2  "tenants"    one thread per tenant — request spans ("X") from
                         arrival to completion (queueing + service) with
                         SLO-met in args, plus admission-rejection
-                        instants ("i"): interference as it happens
+                        instants ("i"): interference as it happens; a
+                        served request's ``STAGES`` as one span per stage,
+                        named by the stage that ends it
     pid 3  "control"    router decisions (with the price vector that
                         justified them), autoscale events, and partition
                         assign/replan events as instants
@@ -27,7 +31,13 @@ from __future__ import annotations
 import json
 from typing import Dict, List
 
-from repro.obs.recorder import FlightRecorder
+from repro.obs.recorder import (
+    STAGE_COLUMNS,
+    STEP_COLUMNS,
+    STAGES,
+    STEP_STAMPS,
+    FlightRecorder,
+)
 
 PID_REPLICAS = 1
 PID_TENANTS = 2
@@ -40,6 +50,21 @@ _TID_PARTITION = 2
 def _meta(pid: int, tid: int, name: str, value: str) -> Dict:
     return {"ph": "M", "pid": pid, "tid": tid, "name": name,
             "args": {"name": value}}
+
+
+def _phase_spans(add, pid: int, tid: int, cat: str, names, stamps,
+                 args: Dict) -> None:
+    """One span per interval between consecutive stamped instants (NaN:
+    not stamped), named by the instant that ends it."""
+    prev = None
+    for name, t in zip(names, stamps):
+        if t != t:
+            continue
+        if prev is not None:
+            add({"ph": "X", "pid": pid, "tid": tid, "ts": prev * 1e6,
+                 "dur": (t - prev) * 1e6, "cat": cat, "name": name,
+                 "args": args})
+        prev = t
 
 
 def chrome_trace_events(rec: FlightRecorder) -> List[Dict]:
@@ -56,6 +81,7 @@ def chrome_trace_events(rec: FlightRecorder) -> List[Dict]:
         s = rec.shards[rid]
         tenants.update(s._arr_tenant)
         tenants.update(s._req_tenant)
+        tenants.update(s._stg_tenant)
         label = f"replica {rid}"
         if s.spec_name:
             label += f" ({s.spec_name})"
@@ -122,6 +148,14 @@ def chrome_trace_events(rec: FlightRecorder) -> List[Dict]:
                  "name": "preempt",
                  "args": {"bucket": labels[bi], "tenant": tenant,
                           "est_ms": est * 1e3, "victims": victims}})
+        for i, row in enumerate(zip(*(getattr(s, c) for c in STEP_COLUMNS))):
+            _phase_spans(add, PID_REPLICAS, rid, "step", STEP_STAMPS, row,
+                         {"step": i, "tokens": s._stp_tokens[i],
+                          "tenants": s._stp_tenants[i]})
+        for i, row in enumerate(zip(*(getattr(s, c)
+                                      for c in STAGE_COLUMNS))):
+            _phase_spans(add, PID_TENANTS, s._stg_tenant[i], "stage", STAGES,
+                         row, {"request": s._stg_id[i], "replica": rid})
 
     # --------------------------------------------------------- fleet level
     off = 0

@@ -35,6 +35,7 @@ from repro.config import ScheduleConfig
 from repro.core.scheduler import DynamicSpaceTimeScheduler
 from repro.core.workload import Workload
 from repro.models import Model
+from repro.obs.recorder import STEP_STAMPS
 from repro.serving.kv_cache import SlotManager
 from repro.serving.request import InferenceRequest, RequestState
 from repro.serving.sampling import SamplingParams, sample
@@ -84,6 +85,37 @@ def engine_programs(model: Model, cache_len: int) -> EnginePrograms:
 
     return EnginePrograms(*(jax.jit(f) for f in (
         decode_all, decode_one, prefill, prefill_cont)))
+
+
+class _StepPhases:
+    """One decode step's ``STEP_STAMPS`` on the host clock, taken at the
+    synchronisation points the step already has. While a profiler session
+    is active each phase is also a ``TraceAnnotation`` named after the
+    stamp that closes it, so the host phases show under the device rows."""
+
+    __slots__ = ("t", "i", "_open")
+
+    def __init__(self):
+        self.t = [time.perf_counter()] + [float("nan")] * (len(STEP_STAMPS) - 1)
+        self.i = 0
+        self._open = (self._annotate(1)
+                      if jax.profiler.TraceAnnotation.is_enabled() else None)
+
+    @staticmethod
+    def _annotate(i: int):
+        ann = jax.profiler.TraceAnnotation(STEP_STAMPS[i])
+        ann.__enter__()
+        return ann
+
+    def mark(self, i: int) -> None:
+        """Stamp ``STEP_STAMPS[i]``; stamping it again moves it later."""
+        self.t[i] = time.perf_counter()
+        if i != self.i:
+            self.i = i
+            if self._open is not None:
+                self._open.__exit__(None, None, None)
+                self._open = (self._annotate(i + 1)
+                              if i + 1 < len(STEP_STAMPS) else None)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -147,9 +179,10 @@ class MultiTenantEngine:
         self.queue: List[InferenceRequest] = []
         self.active: Dict[tuple, InferenceRequest] = {}  # (tenant, slot) -> req
         self.finished: List[InferenceRequest] = []
-        # flight-recorder shard (repro.obs); the API layer attaches it and
-        # taps scheduler.on_dispatch — the engine only records arrivals
+        # flight-recorder shard (repro.obs): the API layer attaches its
+        # replica's shard, and each decode step writes one row into it
         self.recorder = None
+        self._phases: Optional[_StepPhases] = None  # the step in flight
         self.last_token = np.zeros((R, B), np.int32)
         self.steps = 0
         self.decode_tokens = 0
@@ -175,10 +208,6 @@ class MultiTenantEngine:
     def submit(self, req: InferenceRequest, now: Optional[float] = None) -> None:
         req.arrival_time = now if now is not None else time.perf_counter()
         req.state = RequestState.QUEUED
-        if self.recorder is not None:
-            self.recorder.record_arrival(
-                req.arrival_time, req.tenant_id,
-                ("request", len(req.prompt)), True)
         self.queue.append(req)
 
     # ------------------------------------------------------------------ prefill
@@ -188,6 +217,7 @@ class MultiTenantEngine:
         # wanting fewer compiles should bucket their prompt lengths.
         # Each admitted prefill is a Workload bucketed by prompt length so
         # the scheduler accounts its latency per tenant.
+        rec = self.recorder
         remaining = []
         submitted = False
         for req in self.queue:
@@ -196,6 +226,8 @@ class MultiTenantEngine:
                 remaining.append(req)
                 continue
             req.slot = slot
+            if rec is not None:
+                req.slot_time = time.perf_counter()
             req.state = RequestState.PREFILLING
             ok = self.scheduler.submit(Workload(
                 tenant_id=req.tenant_id,
@@ -229,7 +261,6 @@ class MultiTenantEngine:
             tok = int(jnp.argmax(logits[0]))
             req.generated.append(tok)
             req.first_token_time = time.perf_counter()
-            req.prefill_time = req.first_token_time
             self._scatter_slot(req.tenant_id, req.slot, cache)
             self.slots.set_length(req.tenant_id, req.slot, tokens.shape[1])
             self.last_token[req.tenant_id, req.slot] = tok
@@ -294,19 +325,27 @@ class MultiTenantEngine:
         splits the cohort's workloads across pump batches (caches must
         advance once); later sub-batches reuse the same step's logits."""
         if self._cohort_step != self.steps:
+            phases = self._phases
             lengths = jnp.asarray(self._lengths())
             tokens = jnp.asarray(self.last_token)
             logits, self.caches = self._decode_all(
                 self.stacked_params, tokens, self.caches, lengths
             )
+            if phases is not None:
+                phases.mark(1)
             self._step_logits = jax.block_until_ready(logits)
+            if phases is not None:
+                phases.mark(2)
             self._cohort_step = self.steps
         return [self._step_logits[wl.payload] for wl in batch]
 
     def _execute_decode_tenant(self, batch: List[Workload]) -> List[jax.Array]:
         """time_only executor: a per-tenant program with a device sync per
         dispatch (the CUDA context-switch analogue). Cache/logit updates
-        are staged and scattered into the stacked trees once per step."""
+        are staged and scattered into the stacked trees once per step.
+        A recorded step's ``step.enqueue`` is its first tenant's program
+        returning, its ``step.ready`` the last tenant's sync."""
+        phases = self._phases
         outs = []
         for wl in batch:
             t = wl.payload
@@ -314,7 +353,11 @@ class MultiTenantEngine:
             lengths_t = jnp.asarray(self.slots.lengths(t), jnp.int32)
             lg, nc = self._decode_one(self.stacked_params, self.caches,
                                       np.int32(t), tokens_t, lengths_t)
+            if phases is not None and phases.i < 1:
+                phases.mark(1)
             lg = jax.block_until_ready(lg)
+            if phases is not None:
+                phases.mark(2)
             self._pending_caches[t] = nc
             self._pending_logits[t] = lg
             outs.append(lg)
@@ -350,10 +393,16 @@ class MultiTenantEngine:
         tenant, predictability by construction); in time_only mode each
         tenant gets its own bucket and the scheduler dispatches them
         sequentially.
+
+        With a recorder attached the step writes one row of
+        ``STEP_STAMPS``; it starts after admission, so prefill is not in
+        it.
         """
         self._admit()
         if not self.active:
             return 0
+        rec = self.recorder
+        phases = self._phases = None if rec is None else _StepPhases()
 
         slo_by_tenant: Dict[int, float] = {}
         slots_by_tenant: Dict[int, int] = {}
@@ -388,6 +437,8 @@ class MultiTenantEngine:
         else:
             self._sample_key, sub = jax.random.split(self._sample_key)
             next_tokens = np.asarray(sample(logits, self.cfg.sampling, sub), np.int32)
+        if phases is not None:
+            phases.mark(3)
         produced = 0
         now = time.perf_counter()
         for (t, s), req in list(self.active.items()):
@@ -404,6 +455,10 @@ class MultiTenantEngine:
                 del self.active[(t, s)]
         self.steps += 1
         self.decode_tokens += produced
+        if phases is not None:
+            phases.mark(4)
+            rec.record_step(phases.t, produced, len(slots_by_tenant))
+            self._phases = None
         return produced
 
     def run_until_drained(self, max_steps: int = 10_000) -> None:

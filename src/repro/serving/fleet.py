@@ -40,6 +40,7 @@ already-constructed engine, and the spec layer builds those lazily.
 
 from __future__ import annotations
 
+import math
 import time
 from typing import Callable, Iterable, List, Optional, Sequence, Union
 
@@ -69,17 +70,22 @@ class LiveWorkload:
     ``payload`` carries the request body (e.g. prompt token ids),
     ``result`` receives this item's slice of the batch output, and
     ``done`` is an optional ``threading.Event`` the pump signals on
-    completion — the HTTP front door blocks on it.
+    completion — the HTTP front door blocks on it. While the front door
+    records, ``request_id`` is the id it assigned, and ``EngineReplica``
+    leaves the request's engine-side ``STAGES`` in ``stamps``:
+    (``fleet.dispatch``, ``engine.slot``, ``engine.first_token``,
+    ``engine.last_token``).
     """
 
     __slots__ = ("tenant_id", "bucket", "cost", "slo_s", "kind", "flops",
                  "bytes", "arrival_time", "completion_time", "est_s",
-                 "execute", "payload", "result", "done")
+                 "execute", "payload", "result", "done", "request_id",
+                 "stamps")
 
     merge_family = None
 
     def __init__(self, spec, cost: float, execute=None, payload=None,
-                 done=None):
+                 done=None, request_id=None):
         self.tenant_id = spec.tenant_id
         self.bucket = spec.bucket
         self.cost = cost
@@ -94,6 +100,8 @@ class LiveWorkload:
         self.payload = payload
         self.result = None
         self.done = done
+        self.request_id = request_id
+        self.stamps = None
 
 
 # ----------------------------------------------------------- engine adapters
@@ -161,6 +169,8 @@ class EngineReplica:
         from repro.serving.request import InferenceRequest
 
         engine = self.engine
+        rec = engine.recorder
+        t_dispatch = None if rec is None else time.perf_counter()
         n_tenants = engine.cfg.num_tenants
         reqs = []
         for w in batch:
@@ -171,9 +181,16 @@ class EngineReplica:
                 max_new_tokens=self.max_new_tokens,
                 slo_s=float(w.slo_s) if w.slo_s else 0.1,
             )
+            if rec is not None and getattr(w, "request_id", None) is not None:
+                req.request_id = w.request_id
             reqs.append(req)
             engine.submit(req)
         engine.run_until_drained()
+        if rec is not None:
+            for w, req in zip(batch, reqs):
+                if getattr(w, "request_id", None) is not None:
+                    w.stamps = (t_dispatch, req.slot_time,
+                                req.first_token_time, req.finish_time)
         return [list(req.generated) for req in reqs]
 
 
@@ -301,12 +318,13 @@ class LiveFleet:
 
     def submit_one(self, spec: TenantSpec, cost: float = 0.0,
                    payload=None, done=None,
-                   t_s: Optional[float] = None):
+                   t_s: Optional[float] = None, request_id=None):
         """Route and submit ONE arrival; the serving edge's unit of work.
 
         Returns ``(workload, replica_id, admitted, reason)`` — reason is
         the scheduler's admission code (0 admit, 1 oversubscribed,
-        2 cap, 3 infeasible deadline).
+        2 cap, 3 infeasible deadline). The workload's ``arrival_time`` is
+        the ``fleet.arrive`` stage of ``request_id``'s row.
         """
         if t_s is None:
             t_s = self._wall_clock.now() if self.wall else self.now()
@@ -323,7 +341,7 @@ class LiveFleet:
                                        rids, prices)
         w = LiveWorkload(spec, cost,
                          execute=self.engines[pump.replica_id].execute,
-                         payload=payload, done=done)
+                         payload=payload, done=done, request_id=request_id)
         w.est_s = pump.estimate_item_s(w)
         admitted = pump.submit(w, t_s)
         if admitted:
@@ -418,7 +436,7 @@ class LiveFleet:
         stats = [p.scheduler.stats for p in pumps]
         merged = self._fleet_acc.freeze(
             sim_duration_s=horizon_s,
-            busy_time_s=sum(s.busy_time_s for s in stats),
+            busy_time_s=math.fsum(s.busy_time_s for s in stats),
             dispatches=sum(s.dispatches for s in stats),
             rejected=sum(s.rejected for s in stats),
             evicted_tenants=sum(len(p.scheduler.evicted) for p in pumps),

@@ -34,7 +34,8 @@ class InferenceRequest:
 
     # timing
     arrival_time: float = 0.0
-    prefill_time: Optional[float] = None
+    # a slot was acquired and prefill may start (stamped while recording)
+    slot_time: Optional[float] = None
     first_token_time: Optional[float] = None
     finish_time: Optional[float] = None
 

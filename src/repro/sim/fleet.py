@@ -50,6 +50,7 @@ as the solo simulator.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Callable, Dict, Iterable, List, Optional, Sequence, Union
 
 import numpy as np
@@ -503,7 +504,7 @@ class FleetSimulator:
         stats = [p.scheduler.stats for p in self.pumps]
         return acc.freeze(
             sim_duration_s=horizon,
-            busy_time_s=sum(s.busy_time_s for s in stats),
+            busy_time_s=math.fsum(s.busy_time_s for s in stats),
             dispatches=sum(s.dispatches for s in stats),
             rejected=sum(s.rejected for s in stats),
             evicted_tenants=sum(len(p.scheduler.evicted) for p in self.pumps),

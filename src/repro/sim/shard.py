@@ -304,7 +304,7 @@ def _merge(fleet, shards: List[Dict], t_start: float) -> FleetMetrics:
 
     merged_metrics = merged.freeze(
         sim_duration_s=horizon,
-        busy_time_s=sum(s["busy"] for s in shards),
+        busy_time_s=math.fsum(s["busy"] for s in shards),
         dispatches=sum(s["dispatches"] for s in shards),
         rejected=sum(s["rejected"] for s in shards),
         evicted_tenants=sum(s["evicted"] for s in shards),

@@ -10,6 +10,7 @@ REPRO_LIVE_JAX=1.
 
 import json
 import os
+import time
 
 import pytest
 
@@ -126,6 +127,22 @@ class TestFakeEngine:
         assert m.merged.completed == 300
         assert sum(fleet.routed_counts) == 300
         assert m.router == "least_cost"
+
+    @pytest.mark.parametrize("wall", [True, False])
+    def test_idle_replica_backlog_is_only_its_clock_lead(self, wall):
+        # on the shared wall clock the time since an arrival was stamped
+        # is no backlog, or the replica priced last always looks busier;
+        # a virtual replica's clock can run ahead, and that lead counts
+        fleet = LiveFleet(replicas=2, engine_factory=FakeEngine,
+                          schedule=SCHED, cost_model=BASE,
+                          clock_factory=None if wall else VirtualClock)
+        now = fleet.now()
+        if wall:
+            time.sleep(0.002)
+        else:
+            fleet.pumps[1].clock.advance(0.25)
+        assert [p.backlog_s(now) for p in fleet.pumps] == (
+            [0.0, 0.0] if wall else [0.0, 0.25])
 
 
 # ---------------------------------------------------------------- end to end

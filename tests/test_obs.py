@@ -181,6 +181,87 @@ class TestChromeExport:
         assert rejected and all(ev["ph"] == "i" for ev in rejected)
 
 
+# ------------------------------------------------------------ live recording
+class TestLiveRecording:
+    def test_stage_and_step_rows_export_as_phase_spans(self):
+        from repro.obs.recorder import STAGES, STEP_STAMPS, ReplicaShard
+
+        rec = FlightRecorder()
+        s = rec.shard(1)
+        nan = float("nan")
+        s.record_stages(7, 3, [1.0, 1.1, 1.2, 1.25, 1.3, 1.8, 1.9, 2.0])
+        # an engine that stamps nothing leaves its stages NaN
+        s.record_stages(8, 3, [1.0, 1.2, nan, nan, nan, nan, 1.9, 2.0])
+        s.record_step([1.3, 1.31, 1.35, 1.36, 1.38], 2, 1)
+        assert (s.n_stages, s.n_steps, rec.total_events()) == (2, 1, 3)
+        back = ReplicaShard.from_payload(s.payload())
+        assert list(back._stg_id) == [7, 8]
+        assert list(back._stp_end) == [1.38]
+        events = json.loads(export_chrome_trace(rec))["traceEvents"]
+        stage = [e for e in events if e.get("cat") == "stage"]
+        assert [e["name"] for e in stage] == list(STAGES[1:]) + [
+            "fleet.arrive", "fleet.complete", "front.respond"]
+        assert {(e["pid"], e["tid"]) for e in stage} == {(2, 3)}
+        assert sum(e["dur"] for e in stage[:7]) == pytest.approx(1e6)
+        assert [e["args"]["request"] for e in stage] == [7] * 7 + [8] * 3
+        step = [e for e in events if e.get("cat") == "step"]
+        assert [e["name"] for e in step] == list(STEP_STAMPS[1:])
+        assert {(e["pid"], e["tid"]) for e in step} == {(1, 1)}
+        assert step[0]["args"] == {"step": 0, "tokens": 2, "tenants": 1}
+        assert {"ph": "M", "pid": 2, "tid": 3, "name": "thread_name",
+                "args": {"name": "tenant 3"}} in events
+
+    def test_front_door_writes_one_stage_row_per_answer(self, tmp_path):
+        """Concurrent predicts, with the interpreter switching threads
+        often: every answer leaves exactly one whole row (a lost or torn
+        append would leave the columns unequal or the ids short)."""
+        import sys
+        from concurrent.futures import ThreadPoolExecutor
+
+        from repro.api.spec import ServeSpec
+        from repro.launch.serve import FleetServer
+        from repro.obs.recorder import STAGE_COLUMNS
+
+        trace = tmp_path / "trace.json"
+        system = SystemSpec.from_dict({
+            "mode": "live",
+            "workload": {"mix": "sgemm", "tenants": 2, "events": 10,
+                         "seed": 7, "rate_hz": 1000.0, "arch": "fake",
+                         "max_new_tokens": 4},
+            "fleet": {"replicas": 2},
+            "router": {"policy": "round_robin"},
+            "observability": {"enabled": True, "trace_path": str(trace)}})
+        srv = FleetServer(ServeSpec(system=system, port=0,
+                                    request_timeout_s=10.0,
+                                    poll_interval_s=0.01))
+        n = 64
+        interval = sys.getswitchinterval()
+        srv.start()
+        try:
+            sys.setswitchinterval(1e-5)
+            with ThreadPoolExecutor(32) as pool:
+                outs = list(pool.map(lambda i: srv.predict(i % 2, [1, i]),
+                                     range(n)))
+            srv.report()
+        finally:
+            sys.setswitchinterval(interval)
+            srv.shutdown()
+        assert all(o["status"] == 200 for o in outs)
+        shards = srv.recorder.shards.values()
+        assert sorted(i for s in shards for i in s._stg_id) == list(range(n))
+        for s in shards:
+            cols = ("_stg_id", "_stg_tenant") + STAGE_COLUMNS
+            assert {len(getattr(s, c)) for c in cols} == {s.n_stages}
+            for row in zip(s._stg_receive, s._stg_arrive, s._stg_complete,
+                           s._stg_respond):
+                assert list(row) == sorted(row)
+            assert all(t != t for t in s._stg_slot)  # the fake engine's
+        names = [e["name"] for e in json.loads(trace.read_text())
+                 ["traceEvents"] if e.get("cat") == "stage"]
+        assert names.count("fleet.arrive") == names.count(
+            "front.respond") == n
+
+
 # ------------------------------------------------------------------ telemetry
 class TestTelemetry:
     def test_series_sums_match_totals(self):

@@ -100,3 +100,31 @@ def test_report_metrics():
     assert rep["finished"] == 2.0
     assert rep["decode_tokens"] >= 4.0
     assert "req_mean_latency_s" in rep
+
+
+@pytest.mark.parametrize("mode", ["space_time", "time_only"])
+def test_recorded_steps_and_slot_stamps(mode):
+    """With a recorder shard attached, every decode step writes one row
+    of ordered host stamps, and each request stamps its slot before its
+    first token; without one, nothing is stamped."""
+    from repro.obs.recorder import STEP_COLUMNS, ReplicaShard
+
+    cfg, m, tenant_params, eng = _setup("stablelm-1.6b", R=2, mode=mode)
+    _, _, _, off = _setup("stablelm-1.6b", R=2, mode=mode)
+    shard = eng.recorder = ReplicaShard(0)
+    rng = np.random.RandomState(4)
+    for e in (eng, off):
+        for t in (0, 0, 1):
+            e.submit(InferenceRequest(
+                tenant_id=t, prompt=list(rng.randint(1, cfg.vocab_size, 4)),
+                max_new_tokens=3))
+        e.run_until_drained()
+    rows = np.array([list(getattr(shard, c)) for c in STEP_COLUMNS]).T
+    assert shard.n_steps == eng.steps == len(rows)
+    assert np.isfinite(rows).all() and (np.diff(rows, axis=1) >= 0).all()
+    assert sum(shard._stp_tokens) == eng.decode_tokens
+    assert max(shard._stp_tenants) == 2
+    assert all(r.arrival_time <= r.slot_time <= r.first_token_time
+               <= r.finish_time for r in eng.finished)
+    assert off.recorder is None and off._phases is None
+    assert all(r.slot_time is None for r in off.finished)
