@@ -216,13 +216,13 @@ def reference_check(engine, requests, outs) -> None:
     import jax.numpy as jnp
     import numpy as np
 
-    from repro.serving.engine import _tenant
+    from repro.core.tenancy import take_tenant
 
     model = engine.model
     cache_len = engine.cfg.cache_len
     with reference_kernels():
         def ref_prefill(params, t, tokens):
-            p = _tenant(params, t)
+            p = take_tenant(params, t)
             # an f32 residual stream makes every matmul promote its bf16
             # weight to f32: the weights are the served ones, exactly
             p = dict(p, embed=p["embed"].astype(jnp.float32))

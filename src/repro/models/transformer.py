@@ -13,6 +13,12 @@ Three entry points (all pure):
     forward_train   tokens -> (loss, metrics)
     forward_prefill tokens -> (last-token logits, caches)
     forward_decode  token  -> (logits, new caches)
+
+``forward_prefill`` and ``forward_decode`` also serve one tenant straight
+out of an R-tenant stacked tree (``core.tenancy``) given its index: the
+leaves outside the layer stack are picked on their tenant axis, and the
+scan over the layer-major stack picks the tenant's row of each layer, so
+the tenant's stack is never gathered whole.
 """
 
 from __future__ import annotations
@@ -25,6 +31,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.config import AttentionKind, BlockKind, Modality, ModelConfig
+from repro.core.tenancy import LAYER_MAJOR_KEY, take_tenant
 from repro.distributed.constraints import constrain
 from repro.models import attention, layers, moe, rwkv, ssm
 
@@ -274,6 +281,18 @@ class Model:
             logits = layers.softcap(logits.astype(jnp.float32), cfg.logit_softcap)
         return constrain(logits, "batch", None, "model")
 
+    # -------------------------------------------------------------- tenants
+    @staticmethod
+    def _outside_stack(params: Params, tenant: Optional[jax.Array]) -> Params:
+        """With a ``tenant`` index, ``params`` is the stacked tree: pick
+        the tenant's leaves outside the layer stack, and leave the stack
+        layer-major for ``_run_stack`` to pick layer by layer."""
+        if tenant is None:
+            return params
+        rest = take_tenant(
+            {k: v for k, v in params.items() if k != LAYER_MAJOR_KEY}, tenant)
+        return {**rest, LAYER_MAJOR_KEY: params[LAYER_MAJOR_KEY]}
+
     # -------------------------------------------------------------- stack walk
     def _run_stack(
         self,
@@ -282,7 +301,11 @@ class Model:
         mode: str,
         caches: Optional[Cache],
         lengths: Optional[jax.Array],
+        tenant: Optional[jax.Array] = None,
     ) -> Tuple[jax.Array, Optional[Cache], jax.Array]:
+        """``tenant``: an index into the stack's tenant axis, where
+        ``params`` comes from ``_outside_stack``; the caches are the
+        tenant's own."""
         cfg = self.cfg
         unit, reps, rem = find_unit(cfg)
         shared = params.get("shared_attn")
@@ -295,7 +318,13 @@ class Model:
             new_caches = {}
             for p, (kind, ak) in enumerate(unit):
                 pkey = f"pos{p}"
-                bparams = shared if kind == BlockKind.HYBRID_SHARED_ATTN else u_params[pkey]
+                if kind == BlockKind.HYBRID_SHARED_ATTN:
+                    bparams = shared
+                elif tenant is None:
+                    bparams = u_params[pkey]
+                else:
+                    # one layer of the layer-major stack is tenant-major
+                    bparams = take_tenant(u_params[pkey], tenant)
                 bcache = None if u_caches is None else u_caches[pkey]
                 if mode == "train" and self.remat == "block":
                     # per-block remat INSIDE the unit: the unit-level
@@ -391,6 +420,7 @@ class Model:
         prefix_embeds: Optional[jax.Array] = None,
         caches: Optional[Cache] = None,
         start: Optional[jax.Array] = None,
+        tenant: Optional[jax.Array] = None,
     ) -> Tuple[jax.Array, Cache]:
         """Prefill. Returns (last-position logits, caches).
 
@@ -400,20 +430,25 @@ class Model:
         per chunk length, exact state carry for attention/SSM/RWKV. Not
         supported for sliding-window ring caches (gemma3-style local
         layers raise NotImplementedError).
+
+        ``tenant``: ``params`` is the stacked tree and this prefills that
+        tenant; ``caches`` (if given) and the result are the tenant's own.
         """
         cfg = self.cfg
         B, S = tokens.shape
+        params = self._outside_stack(params, tenant)
         x = self._embed(params, tokens)
         if cfg.num_prefix_embeddings and prefix_embeds is not None:
             pref = prefix_embeds.astype(x.dtype) @ params["frontend_proj"]
             x = jnp.concatenate([pref, x[:, prefix_embeds.shape[1]:, :]], axis=1)
         if caches is None:
             caches = self.init_caches(B, cache_len)
-            x, new_caches, _ = self._run_stack(params, x, "prefill", caches, None)
+            x, new_caches, _ = self._run_stack(
+                params, x, "prefill", caches, None, tenant)
         else:
             start = jnp.asarray(0 if start is None else start, jnp.int32)
             x, new_caches, _ = self._run_stack(
-                params, x, "prefill_continue", caches, start
+                params, x, "prefill_continue", caches, start, tenant
             )
         logits = self._logits(params, x[:, -1:, :])
         return logits[:, 0, :], new_caches
@@ -424,10 +459,16 @@ class Model:
         token: jax.Array,       # (B,) int32 current token
         caches: Cache,
         lengths: jax.Array,     # (B,) tokens already in cache
+        tenant: Optional[jax.Array] = None,
     ) -> Tuple[jax.Array, Cache]:
-        """One decode step. Returns (logits (B, V), new caches)."""
+        """One decode step. Returns (logits (B, V), new caches).
+
+        ``tenant``: ``params`` is the stacked tree and this decodes that
+        tenant; ``caches`` and the result are the tenant's own."""
+        params = self._outside_stack(params, tenant)
         x = self._embed(params, token[:, None])
-        x, new_caches, _ = self._run_stack(params, x, "decode", caches, lengths)
+        x, new_caches, _ = self._run_stack(
+            params, x, "decode", caches, lengths, tenant)
         logits = self._logits(params, x)
         return logits[:, 0, :], new_caches
 

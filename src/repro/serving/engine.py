@@ -33,18 +33,15 @@ import numpy as np
 
 from repro.config import ScheduleConfig
 from repro.core.scheduler import DynamicSpaceTimeScheduler
+from repro.core.tenancy import (
+    broadcast_tenants, map_tenant_axis, stack_params, take_tenant,
+    tenant_axes, tenant_counts)
 from repro.core.workload import Workload
 from repro.models import Model
 from repro.obs.recorder import STEP_STAMPS
 from repro.serving.kv_cache import SlotManager
 from repro.serving.request import InferenceRequest, RequestState
 from repro.serving.sampling import SamplingParams, sample
-
-
-def _tenant(stacked: Any, t) -> Any:
-    """Tenant ``t``'s slice of a stacked tree (``t`` may be traced)."""
-    return jax.tree.map(
-        lambda x: jax.lax.dynamic_index_in_dim(x, t, keepdims=False), stacked)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -62,26 +59,34 @@ def engine_programs(model: Model, cache_len: int) -> EnginePrograms:
     """Jitted programs for one (model, cache length), shared by every
     engine that serves it, so replicas on one device compile once.
 
-    Per-tenant programs take the whole stacked tree plus a traced tenant
-    index and select inside the program: one compile serves every
-    tenant, and no eager per-call copy of a tenant's weights is made.
+    The stacked trees keep their layer stack layer-major
+    (``core.tenancy``): ``decode_all`` maps every tenant over each leaf's
+    own tenant axis, so the model's scan over the layers reads the
+    weights and caches in place. Per-tenant programs take the whole
+    stacked tree plus a traced tenant index and pick the tenant's rows
+    layer by layer inside the scan: one compile serves every tenant, and
+    no copy of a tenant's whole stack is made.
     """
 
     def decode_all(params, tokens, caches, lengths):
-        return jax.vmap(model.forward_decode)(params, tokens, caches, lengths)
+        cache_axes = tenant_axes(caches)
+        return jax.vmap(
+            model.forward_decode,
+            in_axes=(tenant_axes(params), 0, cache_axes, 0),
+            out_axes=(0, cache_axes))(params, tokens, caches, lengths)
 
     def decode_one(params, caches, t, tokens, lengths):
         return model.forward_decode(
-            _tenant(params, t), tokens, _tenant(caches, t), lengths)
+            params, tokens, take_tenant(caches, t), lengths, tenant=t)
 
     def prefill(params, t, tokens):
         return model.forward_prefill(
-            _tenant(params, t), tokens, cache_len=cache_len)
+            params, tokens, cache_len=cache_len, tenant=t)
 
     def prefill_cont(params, t, tokens, caches, start):
         return model.forward_prefill(
-            _tenant(params, t), tokens, cache_len=cache_len,
-            caches=caches, start=start)
+            params, tokens, cache_len=cache_len,
+            caches=caches, start=start, tenant=t)
 
     return EnginePrograms(*(jax.jit(f) for f in (
         decode_all, decode_one, prefill, prefill_cont)))
@@ -140,18 +145,19 @@ class EngineConfig:
 class MultiTenantEngine:
     """R tenants' decode loop over ONE stacked weight tree.
 
-    ``stacked_params`` carries every tenant's weights along a leading
+    ``stacked_params`` carries every tenant's weights along each leaf's
     tenant axis (``core.tenancy.stack_params`` / ``init_stacked``); the
     engine holds no per-tenant copy, so engines on one device can share
-    the tree. The stacked caches are placed on the device that holds the
-    weights, which is what pins a fleet replica to its chip.
+    the tree. The stacked caches, in the same layout, are placed on the
+    device that holds the weights, which is what pins a fleet replica to
+    its chip.
     """
 
     def __init__(self, model: Model, stacked_params: Any, config: EngineConfig):
-        lead = {x.shape[0] for x in jax.tree.leaves(stacked_params)}
-        if lead != {config.num_tenants}:
+        counts = tenant_counts(stacked_params)
+        if counts != {config.num_tenants}:
             raise ValueError(
-                f"stacked params lead with {sorted(lead)} tenants, "
+                f"stacked params hold {sorted(counts)} tenants, "
                 f"config has {config.num_tenants}")
         self.model = model
         self.cfg = config
@@ -160,10 +166,7 @@ class MultiTenantEngine:
         R, B = config.num_tenants, config.slots_per_tenant
         single = model.init_caches(B, config.cache_len)
         device = next(iter(jax.tree.leaves(stacked_params)[0].devices()))
-        self.caches = jax.device_put(
-            jax.tree.map(
-                lambda x: jnp.broadcast_to(x[None], (R,) + x.shape), single),
-            device)
+        self.caches = jax.device_put(broadcast_tenants(single, R), device)
         self.slots = SlotManager(R, B)
 
         # the unified scheduling core: prefill + decode cohorts flow
@@ -286,28 +289,20 @@ class MultiTenantEngine:
         return logits, cache
 
     def _scatter_slot(self, tenant: int, slot: int, single_cache: Any) -> None:
-        """Insert a prefilled (batch=1) cache into the stacked cohort cache."""
+        """Insert a prefilled (batch=1) cache into the stacked cohort cache.
 
-        def upd(big: jax.Array, small: jax.Array, slot_axis: int) -> jax.Array:
+        Each leaf's slot axis follows its tenant axis: unit caches are
+        (reps, R, B, ...), the others (R, B, ...)."""
+
+        def upd(ax: int, big: jax.Array, small: jax.Array) -> jax.Array:
             idx = [0] * big.ndim
-            idx[0] = tenant
-            idx[slot_axis] = slot
+            idx[ax] = tenant
+            idx[ax + 1] = slot
             return jax.lax.dynamic_update_slice(
-                big, small[None].astype(big.dtype), tuple(idx)
+                big, jnp.expand_dims(small, ax).astype(big.dtype), tuple(idx)
             )
 
-        # unit caches: leaf (R, reps, B, ...) -> slot axis 2
-        self.caches["unit"] = jax.tree.map(
-            lambda big, small: upd(big, small, 2),
-            self.caches["unit"],
-            single_cache["unit"],
-        )
-        # rem caches: leaf (R, B, ...) -> slot axis 1
-        self.caches["rem"] = jax.tree.map(
-            lambda big, small: upd(big, small, 1),
-            self.caches["rem"],
-            single_cache["rem"],
-        )
+        self.caches = map_tenant_axis(upd, self.caches, single_cache)
 
     # ------------------------------------------------------------------ decode
     def _lengths(self) -> np.ndarray:
@@ -369,13 +364,11 @@ class MultiTenantEngine:
             return
         ts = sorted(self._pending_caches)
         idx = jnp.asarray(ts)
-        small = jax.tree.map(
-            lambda *xs: jnp.stack(xs), *[self._pending_caches[t] for t in ts]
-        )
-        self.caches = jax.tree.map(
-            lambda big, sm: big.at[idx].set(sm.astype(big.dtype)),
-            self.caches, small,
-        )
+        small = stack_params([self._pending_caches[t] for t in ts])
+        self.caches = map_tenant_axis(
+            lambda ax, big, sm: big.at[(slice(None),) * ax + (idx,)].set(
+                sm.astype(big.dtype)),
+            self.caches, small)
         lgs = jnp.stack([self._pending_logits[t] for t in ts])
         if self._step_logits is None or self._step_logits.shape[-1] != lgs.shape[-1]:
             R, B = self.cfg.num_tenants, self.cfg.slots_per_tenant

@@ -228,7 +228,10 @@ class TestLiveSpec:
         # one stacked tree for both replicas, weights and caches on the
         # device the replica was placed on
         assert a.stacked_params is b.stacked_params
+        from repro.core.tenancy import tenant_counts
+
         device = jax.devices()[0]
         for x in jax.tree.leaves((a.stacked_params, a.caches)):
             assert x.devices() == {device}
-            assert x.shape[0] == 2
+        for tree in (a.stacked_params, a.caches):
+            assert tenant_counts(tree) == {2}
