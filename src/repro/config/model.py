@@ -29,6 +29,7 @@ class BlockKind(str, enum.Enum):
     ATTN_MLP = "attn_mlp"        # standard transformer block (attention + MLP/FFN)
     ATTN_MOE = "attn_moe"        # attention + mixture-of-experts FFN
     MAMBA2 = "mamba2"            # Mamba2 SSM block
+    MAMBA2_MLP = "mamba2_mlp"    # Mamba2 mixer, then a gated MLP (granite-4.0-h)
     RWKV6 = "rwkv6"              # RWKV-6 "Finch" time-mix + channel-mix block
     HYBRID_SHARED_ATTN = "hybrid_shared_attn"  # Zamba2 shared attention block
 
@@ -69,6 +70,8 @@ class SSMConfig:
     conv_width: int = 4            # depthwise causal conv width (Mamba2)
     chunk_size: int = 256          # chunked-scan block length
     dt_rank: int = 0               # unused by Mamba2 (scalar dt per head)
+    # groups of the gated RMSNorm before out_proj; 0 -> one per SSM head
+    norm_groups: int = 0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -92,6 +95,8 @@ class ModelConfig:
     global_every: int = 0           # gemma3: 1 global layer every N (0 = never)
     qkv_bias: bool = False          # qwen2 uses bias on QKV
     rope_theta: float = 10_000.0
+    rope: bool = True               # False: no position embedding (NoPE)
+    attention_scale: float = 0.0    # score scale; 0 -> 1/sqrt(head_dim)
     logit_softcap: float = 0.0      # gemma-style final-logit soft-capping
 
     # --- FFN geometry -------------------------------------------------------
@@ -118,6 +123,12 @@ class ModelConfig:
     norm_eps: float = 1e-6
     tie_embeddings: bool = False
     scale_embed: bool = False       # gemma-family sqrt(d_model) embed scaling
+    # muP multipliers (granite): embeddings times ``embedding_multiplier``,
+    # each residual branch times ``residual_multiplier``, logits divided by
+    # ``logits_scaling``; 1.0 leaves the program as it was
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
     dtype: str = "bfloat16"
 
     # ------------------------------------------------------------------ helpers
@@ -256,6 +267,18 @@ class ModelConfig:
             p += d_inner                                               # gated rmsnorm
             p += d_inner * d                                           # out_proj
             return p + d  # pre-norm
+        if kind == BlockKind.MAMBA2_MLP:
+            # the program's own Mamba2 mixer: one group of B and C (N wide),
+            # conv with bias over x, B, C, and A_log, D, dt_bias per head
+            assert self.ssm is not None
+            s = self.ssm
+            d_inner = s.expand * d
+            nheads = s.num_ssm_heads or d_inner // s.head_dim
+            conv = d_inner + 2 * s.state_dim
+            p = d * (2 * d_inner + 2 * s.state_dim + nheads)           # z, x, B, C, dt
+            p += (s.conv_width + 1) * conv + 3 * nheads + d_inner      # conv, A/D/dt, norm
+            p += d_inner * d                                           # out_proj
+            return p + self._mlp_params() + 2 * d  # two pre-norms
         if kind == BlockKind.RWKV6:
             # time-mix (r,k,v,g,w projections + output) + channel-mix
             p = 4 * d * d + d * d  # r,k,v,g + output

@@ -90,6 +90,7 @@ def smoke_variant(cfg: ModelConfig, *, num_layers: int = 2, d_model: int = 256) 
             expand=cfg.ssm.expand,
             conv_width=cfg.ssm.conv_width,
             chunk_size=16,
+            norm_groups=cfg.ssm.norm_groups,
         )
 
     pattern = None

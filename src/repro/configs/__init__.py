@@ -17,6 +17,7 @@ from repro.configs import (  # noqa: F401
     gemma3_27b,
     rwkv6_1_6b,
     paper_sgemm,
+    granite_4_0_h_micro,
 )
 
 ASSIGNED_ARCHS = [

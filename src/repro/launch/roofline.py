@@ -366,7 +366,7 @@ def analytic_cost(cfg, shape, *, remat: bool = True) -> Dict[str, float]:
         H = s.num_ssm_heads or d_inner // s.head_dim
         P, N, L = s.head_dim, s.state_dim, s.chunk_size
         for kind in cfg.layer_pattern:
-            if kind == BlockKind.MAMBA2:
+            if kind in (BlockKind.MAMBA2, BlockKind.MAMBA2_MLP):
                 if shape.kind in ("train", "prefill"):
                     # intra-chunk: scores 2*T*L*N + y 2*T*L*H*P (causal ~ /2)
                     ssm_flops += B * S * (L * N + L * H * P) \
@@ -461,7 +461,7 @@ def cache_bytes(cfg, shape) -> float:
             ak = cfg.attention_kind_at(i)
             s_alloc = S if ak.value == "full" else min(cfg.sliding_window or S, S)
             total += 2.0 * B * cfg.num_kv_heads * s_alloc * cfg.head_dim * dt_bytes
-        elif kind == BlockKind.MAMBA2 and cfg.ssm is not None:
+        elif kind in (BlockKind.MAMBA2, BlockKind.MAMBA2_MLP) and cfg.ssm is not None:
             s = cfg.ssm
             d_inner = s.expand * cfg.d_model
             H = s.num_ssm_heads or d_inner // s.head_dim

@@ -4,7 +4,8 @@ Cache contract: each attention layer owns ``{"k": (B, Hkv, S_alloc, D),
 "v": (B, Hkv, S_alloc, D)}`` where ``S_alloc`` is the full sequence length
 for global layers and ``min(window, S)`` for sliding-window layers (ring
 buffer). Keys are stored with RoPE already applied, so ring-buffer slots
-stay position-correct.
+stay position-correct. A NoPE model (``cfg.rope`` off) rotates nothing,
+and ``cfg.attention_scale``, where set, replaces 1/sqrt(head_dim).
 """
 
 from __future__ import annotations
@@ -72,6 +73,22 @@ def _project_qkv(params: Params, x: jax.Array, cfg: ModelConfig):
     return q, k, v
 
 
+def _rotate(q: jax.Array, k: jax.Array, positions: jax.Array,
+            cfg: ModelConfig) -> Tuple[jax.Array, jax.Array]:
+    """Rotary embedding of q and k at ``positions`` (B, S); NoPE models
+    (``cfg.rope`` off) take them as they are."""
+    if not cfg.rope:
+        return q, k
+    return (layers.apply_rope(q, positions[:, None, :], cfg.rope_theta),
+            layers.apply_rope(k, positions[:, None, :], cfg.rope_theta))
+
+
+def _scale(cfg: ModelConfig) -> Optional[float]:
+    """The score scale, where the model sets one (else the kernels'
+    1/sqrt(head_dim))."""
+    return cfg.attention_scale or None
+
+
 def attn_forward(
     params: Params,
     x: jax.Array,
@@ -84,10 +101,10 @@ def attn_forward(
     if positions is None:
         positions = jnp.arange(S)[None, :].repeat(B, axis=0)
     q, k, v = _project_qkv(params, x, cfg)
-    q = layers.apply_rope(q, positions[:, None, :], cfg.rope_theta)
-    k = layers.apply_rope(k, positions[:, None, :], cfg.rope_theta)
+    q, k = _rotate(q, k, positions, cfg)
     window = cfg.sliding_window if kind == AttentionKind.SLIDING else 0
-    o = ops.flash_attention(q, k, v, causal=True, window=window)
+    o = ops.flash_attention(q, k, v, causal=True, window=window,
+                            scale=_scale(cfg))
     o = o.transpose(0, 2, 1, 3).reshape(B, S, cfg.num_heads * cfg.head_dim)
     return o @ params["wo"]
 
@@ -103,10 +120,10 @@ def attn_prefill_with_cache(
     B, S, _ = x.shape
     positions = jnp.arange(S)[None, :].repeat(B, axis=0)
     q, k, v = _project_qkv(params, x, cfg)
-    q = layers.apply_rope(q, positions[:, None, :], cfg.rope_theta)
-    k = layers.apply_rope(k, positions[:, None, :], cfg.rope_theta)
+    q, k = _rotate(q, k, positions, cfg)
     window = cfg.sliding_window if kind == AttentionKind.SLIDING else 0
-    o = ops.flash_attention(q, k, v, causal=True, window=window)
+    o = ops.flash_attention(q, k, v, causal=True, window=window,
+                            scale=_scale(cfg))
     o = o.transpose(0, 2, 1, 3).reshape(B, S, cfg.num_heads * cfg.head_dim)
 
     s_alloc = cache["k"].shape[2]
@@ -152,8 +169,7 @@ def attn_prefill_continue(
     B, S, _ = x.shape
     positions = start + jnp.arange(S)[None, :].repeat(B, axis=0)
     q, k, v = _project_qkv(params, x, cfg)
-    q = layers.apply_rope(q, positions[:, None, :], cfg.rope_theta)
-    k = layers.apply_rope(k, positions[:, None, :], cfg.rope_theta)
+    q, k = _rotate(q, k, positions, cfg)
 
     new_k = jax.lax.dynamic_update_slice(
         cache["k"], k.astype(cache["k"].dtype), (0, 0, start, 0)
@@ -164,7 +180,8 @@ def attn_prefill_continue(
 
     s_alloc = new_k.shape[2]
     attn_fn = ref.attention_chunked if s_alloc > 2048 else ref.attention
-    o = attn_fn(q, new_k, new_v, causal=True, q_offset=start)
+    o = attn_fn(q, new_k, new_v, causal=True, q_offset=start,
+                scale=_scale(cfg))
     o = o.transpose(0, 2, 1, 3).reshape(B, S, cfg.num_heads * cfg.head_dim)
     return o @ params["wo"], {"k": new_k, "v": new_v}
 
@@ -181,8 +198,7 @@ def attn_decode(
     B = x.shape[0]
     q, k, v = _project_qkv(params, x, cfg)  # (B, H, 1, D)
     positions = lengths[:, None]  # new token's absolute position
-    q = layers.apply_rope(q, positions[:, None, :], cfg.rope_theta)
-    k = layers.apply_rope(k, positions[:, None, :], cfg.rope_theta)
+    q, k = _rotate(q, k, positions, cfg)
 
     s_alloc = cache["k"].shape[2]
     slot = (lengths % s_alloc).astype(jnp.int32)  # ring slot (== lengths when global)
@@ -200,6 +216,7 @@ def attn_decode(
     )(cache["v"], v, slot)
 
     live = jnp.minimum(lengths + 1, s_alloc).astype(jnp.int32)
-    o = ops.decode_attention(q[:, :, 0, :], new_k, new_v, live)
+    o = ops.decode_attention(q[:, :, 0, :], new_k, new_v, live,
+                             scale=_scale(cfg))
     o = o.reshape(B, 1, cfg.num_heads * cfg.head_dim)
     return o @ params["wo"], {"k": new_k, "v": new_v}

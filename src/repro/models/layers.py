@@ -47,9 +47,11 @@ def rmsnorm(params: Params, x: jax.Array, eps: float = 1e-6) -> jax.Array:
 
 
 def groupnorm_heads(x: jax.Array, num_heads: int, eps: float = 1e-6) -> jax.Array:
-    """Per-head RMS normalization over the trailing dim split into heads.
+    """RMS normalization over the trailing dim split into ``num_heads``
+    equal groups.
 
-    Used by RWKV6 (ln_x over heads) and Mamba2's gated norm variant.
+    Used by RWKV6 (ln_x over heads) and Mamba2's gated norm (over its
+    ``norm_groups``, one group per head by default).
     x: (..., H*P) -> normalized per (H,) group.
     """
     shape = x.shape

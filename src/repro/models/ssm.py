@@ -162,6 +162,16 @@ def ssd_scan(
     return y.astype(xh.dtype), final_state
 
 
+def _gated_norm(params: Params, y: jax.Array, z: jax.Array,
+                cfg: ModelConfig) -> jax.Array:
+    """RMSNorm of ``y * silu(z)`` over ``ssm.norm_groups`` groups of
+    channels (0: one group per SSM head), times the norm's scale."""
+    _, H, _, _ = _dims(cfg)
+    groups = cfg.ssm.norm_groups or H
+    return layers.groupnorm_heads(
+        y * jax.nn.silu(z), groups, cfg.norm_eps) * params["norm"]
+
+
 def _project(params: Params, x: jax.Array, cfg: ModelConfig):
     """Separate, shard-aligned z/x/B/C/dt projections."""
     z = x @ params["wz"]
@@ -230,7 +240,7 @@ def mamba2_forward(
     y = y.reshape(B, S, d_inner).astype(x.dtype)
     y = constrain(y, "batch", None, "model")
 
-    y = layers.groupnorm_heads(y * jax.nn.silu(z), H) * params["norm"]
+    y = _gated_norm(params, y, z, cfg)
     out = y.astype(x.dtype) @ params["out_proj"]
     out = constrain(out, "batch", None, None)  # anchor the residual stream
 
@@ -287,7 +297,7 @@ def mamba2_decode(
     y = y + params["D"][None, :, None] * xh
     y = y.reshape(B, d_inner)
 
-    y = layers.groupnorm_heads(y * jax.nn.silu(z), H) * params["norm"]
+    y = _gated_norm(params, y, z, cfg)
     out = (y.astype(x.dtype) @ params["out_proj"])[:, None, :]
     return out, {
         "conv_x": new_conv_x,

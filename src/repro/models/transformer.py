@@ -2,7 +2,8 @@
 
 The layer stack runs as ``lax.scan`` over the pattern's smallest repeating
 unit (dense: unit=1; llama4: [dense, moe]; zamba2: [5x mamba2, shared-attn];
-gemma3: [5x local, global]) so that full-scale dry-runs lower to compact HLO
+gemma3: [5x local, global]; granite-4.0-h: [5x mamba2, attn, 4x mamba2],
+each with its MLP) so that full-scale dry-runs lower to compact HLO
 — 81 layers become one scan over 13 units plus a short unrolled remainder.
 
 Zamba2's shared attention block is the one weight-sharing case: its params
@@ -66,6 +67,9 @@ def find_unit(cfg: ModelConfig) -> Tuple[List[Tuple[BlockKind, AttentionKind]], 
     return ext, 1, 0  # fallback: whole pattern as one unit
 
 
+_ATTN_KINDS = (BlockKind.ATTN_MLP, BlockKind.ATTN_MOE, BlockKind.HYBRID_SHARED_ATTN)
+
+
 # ----------------------------------------------------------------- block init
 def _block_init(key: jax.Array, kind: BlockKind, cfg: ModelConfig, dtype) -> Params:
     keys = jax.random.split(key, 2)
@@ -88,6 +92,13 @@ def _block_init(key: jax.Array, kind: BlockKind, cfg: ModelConfig, dtype) -> Par
             "norm": layers.rmsnorm_init(cfg.d_model, dtype),
             "mamba": ssm.mamba2_init(keys[0], cfg, dtype),
         }
+    if kind == BlockKind.MAMBA2_MLP:
+        return {
+            "norm1": layers.rmsnorm_init(cfg.d_model, dtype),
+            "mamba": ssm.mamba2_init(keys[0], cfg, dtype),
+            "norm2": layers.rmsnorm_init(cfg.d_model, dtype),
+            "mlp": layers.mlp_init(keys[1], cfg.d_model, cfg.d_ff, cfg.mlp_gated, dtype),
+        }
     if kind == BlockKind.RWKV6:
         return rwkv.rwkv6_init(keys[0], cfg, dtype)
     raise ValueError(kind)
@@ -101,9 +112,9 @@ def _block_cache(
     seq_len: int,
     dtype,
 ) -> Cache:
-    if kind in (BlockKind.ATTN_MLP, BlockKind.ATTN_MOE, BlockKind.HYBRID_SHARED_ATTN):
+    if kind in _ATTN_KINDS:
         return attention.init_cache(cfg, attn_kind, batch, seq_len, dtype)
-    if kind == BlockKind.MAMBA2:
+    if kind in (BlockKind.MAMBA2, BlockKind.MAMBA2_MLP):
         return ssm.init_cache(cfg, batch, dtype)
     if kind == BlockKind.RWKV6:
         return rwkv.init_cache(cfg, batch, dtype)
@@ -111,6 +122,49 @@ def _block_cache(
 
 
 # ----------------------------------------------------------------- block apply
+def _residual(y: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """A residual branch's output, times the model's multiplier."""
+    if cfg.residual_multiplier == 1.0:
+        return y
+    return y * cfg.residual_multiplier
+
+
+def _attention(
+    params: Params,
+    h: jax.Array,
+    attn_kind: AttentionKind,
+    cfg: ModelConfig,
+    mode: str,
+    cache: Optional[Cache],
+    lengths: Optional[jax.Array],
+) -> Tuple[jax.Array, Optional[Cache]]:
+    if mode == "train":
+        return attention.attn_forward(params, h, cfg, attn_kind), None
+    if mode == "prefill":
+        return attention.attn_prefill_with_cache(params, h, cfg, attn_kind, cache)
+    if mode == "prefill_continue":
+        return attention.attn_prefill_continue(
+            params, h, cfg, attn_kind, cache, lengths)
+    return attention.attn_decode(params, h, cfg, attn_kind, cache, lengths)
+
+
+def _mamba2(
+    params: Params,
+    h: jax.Array,
+    cfg: ModelConfig,
+    mode: str,
+    cache: Optional[Cache],
+) -> Tuple[jax.Array, Optional[Cache]]:
+    if mode == "decode":
+        return ssm.mamba2_decode(params, h, cfg, cache)
+    if mode == "prefill_continue":
+        return ssm.mamba2_forward(
+            params, h, cfg, return_cache=True, init_cache_state=cache)
+    y, new_cache = ssm.mamba2_forward(
+        params, h, cfg, return_cache=(mode == "prefill"))
+    return y, (None if mode == "train" else new_cache)
+
+
 def _apply_block(
     params: Params,
     x: jax.Array,
@@ -121,48 +175,37 @@ def _apply_block(
     cache: Optional[Cache],
     lengths: Optional[jax.Array],
 ) -> Tuple[jax.Array, Optional[Cache], jax.Array]:
-    """Returns (x, new_cache, aux_loss)."""
+    """Returns (x, new_cache, aux_loss).
+
+    A mixer (attention or Mamba2) then an MLP or MoE, each
+    ``x + residual_multiplier * f(rmsnorm(x))``; the bare Mamba2 and
+    RWKV6 blocks have no second branch. The layer kinds' bodies run
+    under the named scopes ``attention``, ``mamba2`` and ``mlp``, which
+    the profiler's op metadata carries."""
     aux = jnp.zeros((), jnp.float32)
 
-    if kind in (BlockKind.ATTN_MLP, BlockKind.ATTN_MOE, BlockKind.HYBRID_SHARED_ATTN):
+    if kind in _ATTN_KINDS or kind == BlockKind.MAMBA2_MLP:
         h = layers.rmsnorm(params["norm1"], x, cfg.norm_eps)
-        if mode == "train":
-            a = attention.attn_forward(params["attn"], h, cfg, attn_kind)
-            new_cache = None
-        elif mode == "prefill":
-            a, new_cache = attention.attn_prefill_with_cache(
-                params["attn"], h, cfg, attn_kind, cache
-            )
-        elif mode == "prefill_continue":
-            a, new_cache = attention.attn_prefill_continue(
-                params["attn"], h, cfg, attn_kind, cache, lengths
-            )
+        if kind == BlockKind.MAMBA2_MLP:
+            with jax.named_scope("mamba2"):
+                a, new_cache = _mamba2(params["mamba"], h, cfg, mode, cache)
         else:
-            a, new_cache = attention.attn_decode(
-                params["attn"], h, cfg, attn_kind, cache, lengths
-            )
-        x = x + a
+            with jax.named_scope("attention"):
+                a, new_cache = _attention(
+                    params["attn"], h, attn_kind, cfg, mode, cache, lengths)
+        x = x + _residual(a, cfg)
         h = layers.rmsnorm(params["norm2"], x, cfg.norm_eps)
         if kind == BlockKind.ATTN_MOE:
             y, aux = moe.moe_forward(params["moe"], h, cfg)
         else:
-            y = layers.mlp(params["mlp"], h, cfg.mlp_gated)
-        return x + y, new_cache, aux
+            with jax.named_scope("mlp"):
+                y = layers.mlp(params["mlp"], h, cfg.mlp_gated)
+        return x + _residual(y, cfg), new_cache, aux
 
     if kind == BlockKind.MAMBA2:
         h = layers.rmsnorm(params["norm"], x, cfg.norm_eps)
-        if mode == "decode":
-            y, new_cache = ssm.mamba2_decode(params["mamba"], h, cfg, cache)
-        elif mode == "prefill_continue":
-            y, new_cache = ssm.mamba2_forward(
-                params["mamba"], h, cfg, return_cache=True, init_cache_state=cache
-            )
-        else:
-            y, new_cache = ssm.mamba2_forward(
-                params["mamba"], h, cfg, return_cache=(mode == "prefill")
-            )
-            if mode == "train":
-                new_cache = None
+        with jax.named_scope("mamba2"):
+            y, new_cache = _mamba2(params["mamba"], h, cfg, mode, cache)
         return x + y, new_cache, aux
 
     if kind == BlockKind.RWKV6:
@@ -268,6 +311,8 @@ class Model:
         x = jnp.take(params["embed"], tokens, axis=0)
         if cfg.scale_embed:
             x = x * jnp.asarray(math.sqrt(cfg.d_model), x.dtype)
+        if cfg.embedding_multiplier != 1.0:
+            x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
         return constrain(x, "batch", None, None)
 
     def _logits(self, params: Params, x: jax.Array) -> jax.Array:
@@ -277,6 +322,8 @@ class Model:
             logits = x @ params["embed"].T
         else:
             logits = x @ params["lm_head"]
+        if cfg.logits_scaling != 1.0:
+            logits = logits / jnp.asarray(cfg.logits_scaling, logits.dtype)
         if cfg.logit_softcap > 0.0:
             logits = layers.softcap(logits.astype(jnp.float32), cfg.logit_softcap)
         return constrain(logits, "batch", None, "model")

@@ -44,6 +44,13 @@ from repro.serving.request import InferenceRequest, RequestState
 from repro.serving.sampling import SamplingParams, sample
 
 
+@jax.jit
+def _greedy(logits: jax.Array):
+    """The greedy token of each row of ``logits``, and its logit."""
+    return (jnp.argmax(logits, axis=-1).astype(jnp.int32),
+            jnp.max(logits, axis=-1).astype(jnp.float32))
+
+
 @dataclasses.dataclass(frozen=True)
 class EnginePrograms:
     """The engine's jitted programs over stacked params and caches."""
@@ -261,8 +268,10 @@ class MultiTenantEngine:
             req: InferenceRequest = wl.payload
             tokens = jnp.asarray(np.asarray(req.prompt, np.int32))[None, :]
             logits, cache = self._run_prefill(req.tenant_id, tokens)
-            tok = int(jnp.argmax(logits[0]))
+            tok, value = jax.device_get(_greedy(logits[0]))
+            tok = int(tok)
             req.generated.append(tok)
+            req.token_logits.append(float(value))
             req.first_token_time = time.perf_counter()
             self._scatter_slot(req.tenant_id, req.slot, cache)
             self.slots.set_length(req.tenant_id, req.slot, tokens.shape[1])
@@ -290,6 +299,11 @@ class MultiTenantEngine:
 
     def _scatter_slot(self, tenant: int, slot: int, single_cache: Any) -> None:
         """Insert a prefilled (batch=1) cache into the stacked cohort cache.
+
+        Every leaf is installed whole: keys and values over the whole
+        cache length, and a recurrent layer's conv tails and SSM state.
+        The recurrent state is read by no length mask, so this is what
+        keeps a slot's previous request out of the next one.
 
         Each leaf's slot axis follows its tenant axis: unit caches are
         (reps, R, B, ...), the others (R, B, ...)."""
@@ -426,10 +440,12 @@ class MultiTenantEngine:
         logits = self._step_logits
 
         if self.cfg.sampling.greedy:
-            next_tokens = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
+            next_tokens, values = jax.device_get(_greedy(logits))
         else:
             self._sample_key, sub = jax.random.split(self._sample_key)
-            next_tokens = np.asarray(sample(logits, self.cfg.sampling, sub), np.int32)
+            toks = sample(logits, self.cfg.sampling, sub)
+            next_tokens, values = jax.device_get(
+                (toks, jnp.take_along_axis(logits, toks[..., None], -1)[..., 0]))
         if phases is not None:
             phases.mark(3)
         produced = 0
@@ -437,6 +453,7 @@ class MultiTenantEngine:
         for (t, s), req in list(self.active.items()):
             tok = int(next_tokens[t, s])
             req.generated.append(tok)
+            req.token_logits.append(float(values[t, s]))
             produced += 1
             self.slots.set_length(t, s, self.slots.slots[(t, s)].length + 1)
             self.last_token[t, s] = tok

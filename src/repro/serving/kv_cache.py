@@ -2,8 +2,18 @@
 
 Each tenant owns ``num_slots`` sequence slots inside the stacked cache
 pytree (leading axes: [tenant, ..., batch=slot, ...]). The manager tracks
-slot occupancy and per-slot live lengths; freeing a slot just zeroes its
-length (the decode kernels mask by length, so stale data is never read).
+slot occupancy and per-slot live lengths; freeing a slot only zeroes its
+length, and the slot's cache keeps what its last request left.
+
+Two kinds of state live in a slot. Keys and values are read only up to
+the slot's length (the decode kernels mask by it), so stale positions
+past it are never read. A recurrent layer's state (Mamba2 conv tails
+and SSM state, RWKV state) is masked by nothing: it is read whole at
+every step, and the decode step runs every slot, free ones included.
+What keeps a request's state out of the next one is admission: the
+engine installs the prefilled cache of the new request over every leaf
+of the slot (``MultiTenantEngine._scatter_slot``) before the slot
+decodes again.
 """
 
 from __future__ import annotations
@@ -42,6 +52,9 @@ class SlotManager:
         return None
 
     def release(self, tenant: int, slot: int) -> None:
+        """Free the slot. Its cache is left as it is: the next request
+        must have its whole state installed on admission before the slot
+        is decoded for it (recurrent state is not masked by length)."""
         self.slots[(tenant, slot)] = SlotState()
 
     def set_length(self, tenant: int, slot: int, length: int) -> None:

@@ -31,6 +31,8 @@ class InferenceRequest:
     state: RequestState = RequestState.QUEUED
     slot: Optional[int] = None
     generated: List[int] = dataclasses.field(default_factory=list)
+    # the logit of each generated token, as the engine computed it
+    token_logits: List[float] = dataclasses.field(default_factory=list)
 
     # timing
     arrival_time: float = 0.0

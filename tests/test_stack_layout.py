@@ -4,7 +4,8 @@ on axis 0. The engine's programs read that layout in place and must give
 what each tenant's own unstacked tree gives, for every block family the
 layer scan knows: dense, a two-block unit (llama4: dense + MoE), the
 zamba2 hybrid with its shared attention block (two repeats and an
-unrolled remainder), and rwkv6."""
+unrolled remainder), the granite-4.0-h hybrid (Mamba2 and attention
+layers, each with its MLP, two repeats), and rwkv6."""
 
 import dataclasses
 import functools
@@ -23,10 +24,10 @@ from repro.serving.engine import engine_programs
 
 R, B, PROMPT, CACHE_LEN = 2, 2, 5, 16
 
-# (arch, smoke layers): llama4 and zamba2 get two repeats of their unit,
-# zamba2 also a remainder layer
+# (arch, smoke layers): llama4, zamba2 and granite-4.0-h get two repeats
+# of their unit, zamba2 also a remainder layer
 ARCHS = {"stablelm-1.6b": 2, "llama4-maverick-400b-a17b": 4,
-         "zamba2-7b": 5, "rwkv6-1.6b": 2}
+         "zamba2-7b": 5, "granite-4.0-h-micro": 4, "rwkv6-1.6b": 2}
 
 
 def _model(arch):

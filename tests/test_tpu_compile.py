@@ -161,6 +161,49 @@ def test_stablelm_step_fits_one_chip(chip, program):
         f"{program}: temporaries {mem.temp_size_in_bytes / 1e9:.2f} GB")
 
 
+#: granite-4.0-h-micro at the rag cell's sizes: 2048-token prompts, a
+#: cache of 2048 + 32 + 8 positions; temporaries 0.03 GB (decode_all) and
+#: 0.42 GB (prefill), with room to spare
+GRANITE_TEMP_BYTES = {"decode_all": 0.1e9, "prefill": 0.6e9}
+
+
+@pytest.mark.parametrize("program", ["decode_all", "prefill"])
+def test_granite_hybrid_step_fits_one_chip(chip, program):
+    """The served programs of full-width granite-4.0-h-micro (36 Mamba2 +
+    4 attention layers) with R=2 bf16 tenants compile with the Pallas
+    attention kernels at GQA 4:1 and leave at least 1 GB of one chip's
+    HBM: both tenants' weights (12.77 GB), the caches (conv tails, f32
+    SSM state, KV), outputs and temporaries together."""
+    from repro.config import get_config
+    from repro.models import build_model
+    from repro.serving.engine import engine_programs
+
+    R, B, prompt = 2, 2, 2048
+    cache_len = prompt + 32 + 8
+    model = build_model(get_config("granite-4.0-h-micro"))
+    as_chip = lambda t: jax.tree.map(lambda s: chip(s.shape, s.dtype), t)
+    params, caches = _stacked_shapes(model, R, B, cache_len)
+    params = as_chip(params)
+    progs = engine_programs(model, cache_len)
+    if program == "decode_all":
+        args = (params, chip((R, B), jnp.int32), as_chip(caches),
+                chip((R, B), jnp.int32))
+    else:
+        args = (params, chip((), jnp.int32), chip((1, prompt), jnp.int32))
+    compiled = getattr(progs, program).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text(), "no Pallas kernel"
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert mem.argument_size_in_bytes > 12.7e9  # both tenants' weights
+    print(f"{program}: arguments {mem.argument_size_in_bytes / 1e9:.2f} GB, "
+          f"temporaries {mem.temp_size_in_bytes / 1e9:.2f} GB, "
+          f"total {total / 1e9:.2f} GB")
+    assert total < HBM_BYTES - 1e9, f"{program}: {total / 1e9:.2f} GB"
+    assert mem.temp_size_in_bytes <= GRANITE_TEMP_BYTES[program], (
+        f"{program}: temporaries {mem.temp_size_in_bytes / 1e9:.2f} GB")
+
+
 def _stacked_shapes(model, r, b, cache_len):
     """Shapes of the engine's stacked weights and caches (nothing drawn)."""
     from repro.core.tenancy import broadcast_tenants, init_stacked
