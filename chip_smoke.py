@@ -186,10 +186,13 @@ def decode_program(engine):
     """The engine's decode step over every tenant, compiled as served."""
     import jax.numpy as jnp
 
+    from repro.serving.engine import split_kv
+
     R, B = engine.cfg.num_tenants, engine.cfg.slots_per_tenant
     zeros = jnp.zeros((R, B), jnp.int32)
     return engine._decode_all.lower(
-        engine.stacked_params, zeros, engine.caches, zeros).compile()
+        engine.stacked_params, zeros, *split_kv(engine.caches, engine._kv),
+        zeros).compile()
 
 
 @contextlib.contextmanager

@@ -151,7 +151,7 @@ def cache_specs(cache_shape: Pytree, mesh: Mesh, batch: int) -> Pytree:
     """Specs for the decode-cache pytree (see models.transformer layout).
 
     Leaf layouts (unit caches carry a leading reps axis, rem caches don't):
-        k/v      (B, Hkv, S, D)   attention KV
+        k/v      (B, Hkv, D, S)   attention KV
         conv     (B, W, C)        mamba conv state
         ssm      (B, H, P, N)     mamba SSM state
         wkv      (B, H, N, N)     rwkv state
@@ -180,14 +180,14 @@ def cache_specs(cache_shape: Pytree, mesh: Mesh, batch: int) -> Pytree:
             return False
 
         if name in ("k", "v"):
-            # (B, Hkv, S, D). NEVER shard head_dim D: contracting a
+            # (B, Hkv, D, S). NEVER shard head_dim D: contracting a
             # model-sharded D turns every decode score tensor into a
             # (B,H,S)-sized all-reduce per layer (measured 16.8 MB x L —
             # the dominant collective in the decode baseline). When KV
             # heads don't divide the model axis, shard the SEQUENCE dim
             # instead: softmax/value contractions then reduce to
             # (B,H,D)-sized partials only.
-            b, h, s, d = off, off + 1, off + 2, off + 3
+            b, h, d, s = off, off + 1, off + 2, off + 3
             if bspec is not None and shape[b] % _axis_size(mesh, bspec) == 0:
                 spec[b] = bspec
                 set_if_div(h, "model") or set_if_div(s, "model")

@@ -109,19 +109,42 @@ def flash_attention(
 
 def decode_attention(
     q: jax.Array,
-    k_cache: jax.Array,
-    v_cache: jax.Array,
+    k_new: jax.Array,
+    v_new: jax.Array,
+    k_stack: jax.Array,
+    v_stack: jax.Array,
     lengths: jax.Array,
+    layer,
+    tenant,
     *,
     scale: Optional[float] = None,
     force_pallas: Optional[bool] = None,
 ) -> jax.Array:
-    """One-token decode against a KV cache."""
+    """One-token decode read in place from the KV stacks
+    (``decode_attention.decode_attention``)."""
     if _use_pallas(force_pallas):
         return _da.decode_attention(
-            q, k_cache, v_cache, lengths, scale=scale, interpret=_interpret()
-        )
-    return ref.decode_attention(q, k_cache, v_cache, lengths, scale=scale)
+            q, k_new, v_new, k_stack, v_stack, lengths, layer, tenant,
+            scale=scale, interpret=_interpret())
+    return ref.decode_attention(q, k_new, v_new, k_stack, v_stack, lengths,
+                                layer, tenant, scale=scale)
+
+
+def write_rows(
+    stack: jax.Array,
+    rows: jax.Array,
+    tenant: jax.Array,
+    slot: jax.Array,
+    pos: jax.Array,
+    *,
+    force_pallas: Optional[bool] = None,
+) -> jax.Array:
+    """The decode step's keys (or values) written into a K/V stack in
+    place (``decode_attention.write_rows``)."""
+    if _use_pallas(force_pallas):
+        return _da.write_rows(stack, rows, tenant, slot, pos,
+                              interpret=_interpret())
+    return ref.write_rows(stack, rows, tenant, slot, pos)
 
 
 def wkv6_scan(

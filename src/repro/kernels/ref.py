@@ -139,25 +139,48 @@ def attention_chunked(
 
 def decode_attention(
     q: jax.Array,
-    k_cache: jax.Array,
-    v_cache: jax.Array,
+    k_new: jax.Array,
+    v_new: jax.Array,
+    k_stack: jax.Array,
+    v_stack: jax.Array,
     lengths: jax.Array,
+    layer,
+    tenant,
     *,
     scale: Optional[float] = None,
 ) -> jax.Array:
-    """Single-token decode. q (B,Hq,D), caches (B,Hkv,S,D), lengths (B,)."""
+    """Single-token decode. q (B,Hq,D); the new token's k_new/v_new
+    (B,Hkv,D); stacks (L,T,B,Hkv,D,S) read at ``[layer, tenant]``;
+    lengths (B,) tokens cached before the new one. The new token attends
+    to the live cache positions and to itself; a full ring (S <= length)
+    drops the slot the new token replaces, ``length % S``."""
     B, Hq, D = q.shape
-    _, Hkv, S, _ = k_cache.shape
+    Hkv, S = k_stack.shape[3], k_stack.shape[5]
     q_per_kv = Hq // Hkv
     scale = scale if scale is not None else D ** -0.5
-    qg = q.reshape(B, Hkv, q_per_kv, D)
-    s = jnp.einsum("bhgd,bhsd->bhgs", qg.astype(jnp.float32), k_cache.astype(jnp.float32))
-    s *= scale
-    mask = jnp.arange(S)[None, :] < lengths[:, None]  # (B, S)
+    k = k_stack[layer, tenant].astype(jnp.float32)  # (B, Hkv, D, S)
+    v = v_stack[layer, tenant].astype(jnp.float32)
+    qg = q.reshape(B, Hkv, q_per_kv, D).astype(jnp.float32)
+    s = jnp.einsum("bhgd,bhds->bhgs", qg, k)
+    s_new = jnp.einsum("bhgd,bhd->bhg", qg, k_new.astype(jnp.float32))
+    s = jnp.concatenate([s, s_new[..., None]], axis=-1) * scale
+    pos = jnp.arange(S + 1)[None, :]
+    drop = jnp.where(lengths >= S, lengths % S, -1)[:, None]
+    mask = ((pos < jnp.minimum(lengths, S)[:, None]) & (pos != drop)) | (
+        pos == S)  # (B, S + 1): the live cache, and the new token
     s = jnp.where(mask[:, None, None, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bhgs,bhsd->bhgd", p, v_cache.astype(jnp.float32))
+    o = (jnp.einsum("bhgs,bhds->bhgd", p[..., :S], v)
+         + p[..., S:] * v_new.astype(jnp.float32)[:, :, None, :])
     return o.reshape(B, Hq, D).astype(q.dtype)
+
+
+def write_rows(stack: jax.Array, rows: jax.Array, tenant: jax.Array,
+               slot: jax.Array, pos: jax.Array) -> jax.Array:
+    """stack (L,T,B,Hkv,D,S) with rows (L,N,Hkv,D) at
+    ``[:, tenant[n], slot[n], :, :, pos[n]]``."""
+    return stack.at[:, tenant, slot, :, :, pos].set(
+        jnp.moveaxis(rows, 1, 0).astype(stack.dtype))
 
 
 def wkv6_scan(

@@ -1,16 +1,26 @@
 """GQA attention block: prefill (flash) and single-token decode paths.
 
-Cache contract: each attention layer owns ``{"k": (B, Hkv, S_alloc, D),
-"v": (B, Hkv, S_alloc, D)}`` where ``S_alloc`` is the full sequence length
-for global layers and ``min(window, S)`` for sliding-window layers (ring
-buffer). Keys are stored with RoPE already applied, so ring-buffer slots
-stay position-correct. A NoPE model (``cfg.rope`` off) rotates nothing,
-and ``cfg.attention_scale``, where set, replaces 1/sqrt(head_dim).
+Cache contract: each attention layer owns ``{"k": (B, Hkv, D, S_alloc),
+"v": (B, Hkv, D, S_alloc)}``, positions last, where ``S_alloc`` is the
+full sequence length for global layers and ``min(window, S)`` for
+sliding-window layers (ring buffer). That is the layout the TPU stores
+a head_dim-64 cache in, and the one the decode kernel reads, so the
+decode step reads every layer where it lies. Keys are stored with RoPE
+already applied, so ring-buffer slots stay position-correct. A NoPE
+model (``cfg.rope`` off) rotates nothing, and ``cfg.attention_scale``,
+where set, replaces 1/sqrt(head_dim).
+
+Prefill writes its positions into the cache. The decode step does not
+write inside the layer stack: ``attn_decode`` reads a ``KVRead`` view
+(the layer stacks of K and V, which layer and which tenant) and returns
+the new token's key and value rows, which ``write_rows`` puts at the
+token's slot after the stack, every layer in one kernel call.
 """
 
 from __future__ import annotations
 
-from typing import Dict, Optional, Tuple
+import math
+from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -50,8 +60,44 @@ def init_cache(
     cfg: ModelConfig, kind: AttentionKind, batch: int, seq_len: int, dtype
 ) -> Cache:
     s = cache_alloc_len(cfg, kind, seq_len)
-    shape = (batch, cfg.num_kv_heads, s, cfg.head_dim)
+    shape = (batch, cfg.num_kv_heads, cfg.head_dim, s)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+
+
+class KVRead(NamedTuple):
+    """What one attention layer's decode step reads: the stacks of K and
+    V, ``(L, T, B, Hkv, D, S)`` (layer, tenant, slot, ...), and the layer
+    and tenant of them that are this layer's."""
+
+    k: jax.Array
+    v: jax.Array
+    layer: jax.Array
+    tenant: jax.Array
+
+
+def write_rows(cache: Cache, rows: Cache, lengths: jax.Array) -> Cache:
+    """``cache`` with the new token's rows written at each slot's next
+    position (``lengths % S_alloc``: the ring slot, or the position), in
+    every layer, in place where the cache is donated.
+
+    ``cache`` leaves are ``(*L, *N, Hkv, D, S)`` and ``rows`` leaves
+    ``(*L, *N, Hkv, D)``, where ``N`` is ``lengths``' shape (slots, or
+    tenants and slots) and ``L`` a layer axis or none."""
+    *tenants, B = lengths.shape
+    T = math.prod(tenants)
+    layered = rows["k"].ndim - 2 > lengths.ndim
+    tenant = jnp.repeat(jnp.arange(T, dtype=jnp.int32), B)
+    slot = jnp.tile(jnp.arange(B, dtype=jnp.int32), T)
+
+    def put(c, r):
+        L = c.shape[0] if layered else 1
+        new = ops.write_rows(
+            c.reshape((L, T, B) + c.shape[-3:]),
+            r.reshape((L, T * B) + r.shape[-2:]), tenant, slot,
+            (lengths.reshape(-1) % c.shape[-1]).astype(jnp.int32))
+        return new.reshape(c.shape)
+
+    return {"k": put(cache["k"], rows["k"]), "v": put(cache["v"], rows["v"])}
 
 
 def _project_qkv(params: Params, x: jax.Array, cfg: ModelConfig):
@@ -126,19 +172,19 @@ def attn_prefill_with_cache(
                             scale=_scale(cfg))
     o = o.transpose(0, 2, 1, 3).reshape(B, S, cfg.num_heads * cfg.head_dim)
 
-    s_alloc = cache["k"].shape[2]
+    # the cache keeps positions last: (B, Hkv, D, S)
+    k, v = (x.swapaxes(2, 3).astype(cache["k"].dtype) for x in (k, v))
+    s_alloc = cache["k"].shape[3]
     if s_alloc >= S:
-        new_k = jax.lax.dynamic_update_slice(cache["k"], k.astype(cache["k"].dtype), (0, 0, 0, 0))
-        new_v = jax.lax.dynamic_update_slice(cache["v"], v.astype(cache["v"].dtype), (0, 0, 0, 0))
+        new_k = jax.lax.dynamic_update_slice(cache["k"], k, (0, 0, 0, 0))
+        new_v = jax.lax.dynamic_update_slice(cache["v"], v, (0, 0, 0, 0))
     else:
         # sliding ring buffer: keep the last s_alloc keys, aligned to ring
         # slot (S - s_alloc) % s_alloc onward; store rolled so that slot
         # i holds position (S - s_alloc + i) ... ring write index = pos % s_alloc.
-        tail_k = k[:, :, S - s_alloc :, :]
-        tail_v = v[:, :, S - s_alloc :, :]
         shift = (S - s_alloc) % s_alloc
-        new_k = jnp.roll(tail_k, shift, axis=2).astype(cache["k"].dtype)
-        new_v = jnp.roll(tail_v, shift, axis=2).astype(cache["v"].dtype)
+        new_k = jnp.roll(k[..., S - s_alloc:], shift, axis=3)
+        new_v = jnp.roll(v[..., S - s_alloc:], shift, axis=3)
     return o @ params["wo"], {"k": new_k, "v": new_v}
 
 
@@ -171,17 +217,15 @@ def attn_prefill_continue(
     q, k, v = _project_qkv(params, x, cfg)
     q, k = _rotate(q, k, positions, cfg)
 
-    new_k = jax.lax.dynamic_update_slice(
-        cache["k"], k.astype(cache["k"].dtype), (0, 0, start, 0)
-    )
-    new_v = jax.lax.dynamic_update_slice(
-        cache["v"], v.astype(cache["v"].dtype), (0, 0, start, 0)
-    )
+    new_k, new_v = (
+        jax.lax.dynamic_update_slice(
+            c, x.swapaxes(2, 3).astype(c.dtype), (0, 0, 0, start))
+        for c, x in ((cache["k"], k), (cache["v"], v)))
 
-    s_alloc = new_k.shape[2]
+    s_alloc = new_k.shape[3]
     attn_fn = ref.attention_chunked if s_alloc > 2048 else ref.attention
-    o = attn_fn(q, new_k, new_v, causal=True, q_offset=start,
-                scale=_scale(cfg))
+    o = attn_fn(q, new_k.swapaxes(2, 3), new_v.swapaxes(2, 3), causal=True,
+                q_offset=start, scale=_scale(cfg))
     o = o.transpose(0, 2, 1, 3).reshape(B, S, cfg.num_heads * cfg.head_dim)
     return o @ params["wo"], {"k": new_k, "v": new_v}
 
@@ -191,32 +235,22 @@ def attn_decode(
     x: jax.Array,
     cfg: ModelConfig,
     kind: AttentionKind,
-    cache: Cache,
+    cache: KVRead,
     lengths: jax.Array,
 ) -> Tuple[jax.Array, Cache]:
-    """One-token decode. x: (B, 1, d_model); lengths: (B,) tokens already cached."""
+    """One-token decode. x: (B, 1, d_model); lengths: (B,) tokens already
+    cached. Reads the cache in place and writes nothing: returns the
+    output and the new token's rows ``{"k", "v"}: (B, Hkv, D)`` in the
+    cache's dtype, for ``write_rows``."""
+    del kind  # a ring cache is told by its length: the kernel masks it
     B = x.shape[0]
     q, k, v = _project_qkv(params, x, cfg)  # (B, H, 1, D)
     positions = lengths[:, None]  # new token's absolute position
     q, k = _rotate(q, k, positions, cfg)
-
-    s_alloc = cache["k"].shape[2]
-    slot = (lengths % s_alloc).astype(jnp.int32)  # ring slot (== lengths when global)
-
-    def write(c, kv):
-        # c: (Hkv, S, D), kv: (Hkv, 1, D), slot scalar
-        def upd(c, kv, s):
-            return jax.lax.dynamic_update_slice(c, kv.astype(c.dtype), (0, s, 0))
-        return upd
-    new_k = jax.vmap(
-        lambda c, kv, s: jax.lax.dynamic_update_slice(c, kv.astype(c.dtype), (0, s, 0))
-    )(cache["k"], k, slot)
-    new_v = jax.vmap(
-        lambda c, kv, s: jax.lax.dynamic_update_slice(c, kv.astype(c.dtype), (0, s, 0))
-    )(cache["v"], v, slot)
-
-    live = jnp.minimum(lengths + 1, s_alloc).astype(jnp.int32)
-    o = ops.decode_attention(q[:, :, 0, :], new_k, new_v, live,
+    rows = {"k": k[:, :, 0, :].astype(cache.k.dtype),
+            "v": v[:, :, 0, :].astype(cache.v.dtype)}
+    o = ops.decode_attention(q[:, :, 0, :], rows["k"], rows["v"], cache.k,
+                             cache.v, lengths, cache.layer, cache.tenant,
                              scale=_scale(cfg))
     o = o.reshape(B, 1, cfg.num_heads * cfg.head_dim)
-    return o @ params["wo"], {"k": new_k, "v": new_v}
+    return o @ params["wo"], rows

@@ -15,6 +15,10 @@ Three entry points (all pure):
     forward_prefill tokens -> (last-token logits, caches)
     forward_decode  token  -> (logits, new caches)
 
+The decode step reads every attention layer's cache in place and writes
+the new token's keys and values after the layer stack (``decode_step``
+then ``write_kv``), so no layer's cache passes through the scan.
+
 ``forward_prefill`` and ``forward_decode`` also serve one tenant straight
 out of an R-tenant stacked tree (``core.tenancy``) given its index: the
 leaves outside the layer stack are picked on their tenant axis, and the
@@ -26,7 +30,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional, Set, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -349,19 +353,35 @@ class Model:
         caches: Optional[Cache],
         lengths: Optional[jax.Array],
         tenant: Optional[jax.Array] = None,
+        kv_tenant: Optional[jax.Array] = None,
     ) -> Tuple[jax.Array, Optional[Cache], jax.Array]:
         """``tenant``: an index into the stack's tenant axis, where
         ``params`` comes from ``_outside_stack``; the caches are the
-        tenant's own."""
+        tenant's own.
+
+        In decode mode the attention layers read their caches in place
+        (``attention.KVRead``): the layer scan carries none of them, and
+        their entries of the new caches are the new token's rows.
+        ``kv_tenant``: those caches are the R-tenant stacks
+        (``core.tenancy``'s layout), and this reads that tenant's rows."""
         cfg = self.cfg
         unit, reps, rem = find_unit(cfg)
         shared = params.get("shared_attn")
         aux_total = jnp.zeros((), jnp.float32)
+        in_place = self.kv_entries() if mode == "decode" else set()
+
+        def kv_read(c, layer, layered):
+            """An attention entry as (L, T, B, Hkv, D, S) stacks."""
+            k, v = (x if layered else x[None] for x in (c["k"], c["v"]))
+            if kv_tenant is None:
+                k, v = jnp.expand_dims(k, 1), jnp.expand_dims(v, 1)
+            return attention.KVRead(k, v, layer,
+                                    0 if kv_tenant is None else kv_tenant)
 
         def unit_body(carry, xs):
             x, aux = carry
             x = constrain(x, "batch", None, None)
-            u_params, u_caches = xs
+            u_params, u_caches, layer = xs
             new_caches = {}
             for p, (kind, ak) in enumerate(unit):
                 pkey = f"pos{p}"
@@ -372,7 +392,10 @@ class Model:
                 else:
                     # one layer of the layer-major stack is tenant-major
                     bparams = take_tenant(u_params[pkey], tenant)
-                bcache = None if u_caches is None else u_caches[pkey]
+                if ("unit", pkey) in in_place:
+                    bcache = kv_read(caches["unit"][pkey], layer, True)
+                else:
+                    bcache = None if u_caches is None else u_caches[pkey]
                 if mode == "train" and self.remat == "block":
                     # per-block remat INSIDE the unit: the unit-level
                     # checkpoint bounds the scan, this bounds the recompute
@@ -395,27 +418,23 @@ class Model:
 
         # scanned segment
         if reps > 0:
-            unit_caches = None if caches is None else caches["unit"]
+            unit_caches = None if caches is None else {
+                k: c for k, c in caches["unit"].items()
+                if ("unit", k) not in in_place}
             # shared-attn positions have no stacked params; give scan a dummy leaf
             u_params_xs = dict(params["unit"])
             for p, (kind, _) in enumerate(unit):
                 if kind == BlockKind.HYBRID_SHARED_ATTN:
                     u_params_xs[f"pos{p}"] = jnp.zeros((reps,), jnp.int8)  # placeholder
 
-            def unit_body_wrapped(carry, xs):
-                u_params, u_caches = xs
-                # restore sentinel -> shared handled inside unit_body
-                return unit_body(carry, (u_params, u_caches))
-
+            body = unit_body  # the shared sentinel is handled inside it
             if mode == "train" and self.remat == "block":
-                unit_body_wrapped = jax.checkpoint(
-                    unit_body_wrapped, prevent_cse=False
-                )
+                body = jax.checkpoint(body, prevent_cse=False)
 
             (x, aux_total), new_unit_caches = jax.lax.scan(
-                unit_body_wrapped,
+                body,
                 (x, aux_total),
-                (u_params_xs, unit_caches),
+                (u_params_xs, unit_caches, jnp.arange(reps)),
             )
         else:
             new_unit_caches = None
@@ -426,6 +445,8 @@ class Model:
             kind, ak = unit[j]
             bparams = shared if kind == BlockKind.HYBRID_SHARED_ATTN else params["rem"][f"rem{j}"]
             bcache = None if caches is None else caches["rem"][f"rem{j}"]
+            if ("rem", f"rem{j}") in in_place:
+                bcache = kv_read(bcache, 0, False)
             x, nc, a = _apply_block(bparams, x, kind, ak, cfg, mode, bcache, lengths)
             aux_total = aux_total + a
             if caches is not None:
@@ -500,6 +521,51 @@ class Model:
         logits = self._logits(params, x[:, -1:, :])
         return logits[:, 0, :], new_caches
 
+    def kv_entries(self) -> Set[Tuple[str, str]]:
+        """The cache entries that attention layers own, as ``(group,
+        key)``: ``("unit", "pos<p>")`` and ``("rem", "rem<j>")``."""
+        unit, _, rem = find_unit(self.cfg)
+        return ({("unit", f"pos{p}") for p, (kind, _) in enumerate(unit)
+                 if kind in _ATTN_KINDS}
+                | {("rem", f"rem{j}") for j in range(rem)
+                   if unit[j][0] in _ATTN_KINDS})
+
+    def decode_step(
+        self,
+        params: Params,
+        token: jax.Array,       # (B,) int32 current token
+        caches: Cache,
+        lengths: jax.Array,     # (B,) tokens already in cache
+        tenant: Optional[jax.Array] = None,
+        kv_tenant: Optional[jax.Array] = None,
+    ) -> Tuple[jax.Array, Cache]:
+        """One decode step that writes no key or value. Returns (logits
+        (B, V), new) where ``new`` holds each recurrent layer's new state
+        and, in each attention entry (``kv_entries``), the new token's
+        rows ``{"k", "v"}: (..., B, Hkv, D)`` for ``write_kv``.
+
+        ``tenant``: as in ``forward_decode``. ``kv_tenant``: the attention
+        entries of ``caches`` are the R-tenant stacks, passed whole (not
+        mapped by a tenant vmap), and this reads that tenant's rows of
+        them in place; the other entries are the tenant's own."""
+        params = self._outside_stack(params, tenant)
+        x = self._embed(params, token[:, None])
+        x, new, _ = self._run_stack(
+            params, x, "decode", caches, lengths, tenant, kv_tenant)
+        logits = self._logits(params, x)
+        return logits[:, 0, :], new
+
+    def write_kv(self, caches: Cache, new: Cache, lengths: jax.Array) -> Cache:
+        """``caches`` after ``decode_step``: the new token's rows written
+        into each attention entry at each slot's next position, the other
+        entries ``new``'s. ``lengths``: (B,), or (R, B) for R-tenant
+        stacks."""
+        kv = self.kv_entries()
+        return {g: {k: attention.write_rows(c, new[g][k], lengths)
+                    if (g, k) in kv else new[g][k]
+                    for k, c in sub.items()}
+                for g, sub in caches.items()}
+
     def forward_decode(
         self,
         params: Params,
@@ -512,12 +578,8 @@ class Model:
 
         ``tenant``: ``params`` is the stacked tree and this decodes that
         tenant; ``caches`` and the result are the tenant's own."""
-        params = self._outside_stack(params, tenant)
-        x = self._embed(params, token[:, None])
-        x, new_caches, _ = self._run_stack(
-            params, x, "decode", caches, lengths, tenant)
-        logits = self._logits(params, x)
-        return logits[:, 0, :], new_caches
+        logits, new = self.decode_step(params, token, caches, lengths, tenant)
+        return logits, self.write_kv(caches, new, lengths)
 
 
 def build_model(cfg: ModelConfig, remat: str = "block") -> Model:

@@ -69,18 +69,30 @@ def engine_programs(model: Model, cache_len: int) -> EnginePrograms:
     The stacked trees keep their layer stack layer-major
     (``core.tenancy``): ``decode_all`` maps every tenant over each leaf's
     own tenant axis, so the model's scan over the layers reads the
-    weights and caches in place. Per-tenant programs take the whole
-    stacked tree plus a traced tenant index and pick the tenant's rows
-    layer by layer inside the scan: one compile serves every tenant, and
-    no copy of a tenant's whole stack is made.
+    weights and recurrent state in place. The attention caches are not
+    mapped: every tenant's step reads its rows of the whole K/V stacks in
+    place (``Model.decode_step``), and one scatter after the step writes
+    the new token's keys and values for every tenant and layer
+    (``Model.write_kv``). Per-tenant programs take the whole stacked tree
+    plus a traced tenant index and pick the tenant's rows layer by layer
+    inside the scan: one compile serves every tenant, and no copy of a
+    tenant's whole stack is made.
+
+    Nothing here donates: the engine donates the K/V caches where it
+    calls ``decode_all`` (``donate_caches``).
     """
 
     def decode_all(params, tokens, caches, lengths):
-        cache_axes = tenant_axes(caches)
-        return jax.vmap(
-            model.forward_decode,
-            in_axes=(tenant_axes(params), 0, cache_axes, 0),
-            out_axes=(0, cache_axes))(params, tokens, caches, lengths)
+        kv, axes = model.kv_entries(), tenant_axes(caches)
+        cache_axes = {g: {k: None if (g, k) in kv else axes[g] for k in sub}
+                      for g, sub in caches.items()}
+        logits, new = jax.vmap(
+            lambda p, tok, c, ln, t: model.decode_step(p, tok, c, ln,
+                                                       kv_tenant=t),
+            in_axes=(tenant_axes(params), 0, cache_axes, 0, 0),
+            out_axes=(0, axes),
+        )(params, tokens, caches, lengths, jnp.arange(tokens.shape[0]))
+        return logits, model.write_kv(caches, new, lengths)
 
     def decode_one(params, caches, t, tokens, lengths):
         return model.forward_decode(
@@ -97,6 +109,36 @@ def engine_programs(model: Model, cache_len: int) -> EnginePrograms:
 
     return EnginePrograms(*(jax.jit(f) for f in (
         decode_all, decode_one, prefill, prefill_cont)))
+
+
+def split_kv(caches: Any, kv: frozenset) -> tuple:
+    """(the ``kv`` entries of a cache tree, its other entries), each a
+    tree of the same groups; ``kv`` holds ``(group, key)`` pairs
+    (``Model.kv_entries``)."""
+    return tuple({g: {k: c for k, c in sub.items() if ((g, k) in kv) == want}
+                  for g, sub in caches.items()} for want in (True, False))
+
+
+@functools.lru_cache(maxsize=16)
+def donate_caches(program: Any, kv: frozenset) -> Any:
+    """``program`` (an ``engine_programs`` ``decode_all``) with its K/V
+    caches donated, called as ``(params, tokens, kv_caches, state,
+    lengths)`` on ``split_kv``'s halves of the caches: the step writes
+    the new token's keys and values into the stacks it read instead of
+    into a copy. The recurrent state (``state``) is not donated: the
+    layer scan writes each layer's new state into a fresh stack, which
+    a donated input would have to be copied into on every step.
+
+    Cached by program, as ``engine_programs`` is, so replicas on one
+    device compile it once. The donation is applied here, over whatever
+    ``engine_programs`` returns, and not inside it: a wrapper of
+    ``decode_all`` may still read its input caches after calling it."""
+
+    def decode_all(params, tokens, kv_caches, state, lengths):
+        caches = {g: {**state[g], **kv_caches[g]} for g in state}
+        return program(params, tokens, caches, lengths)
+
+    return jax.jit(decode_all, donate_argnums=2)
 
 
 class _StepPhases:
@@ -157,7 +199,10 @@ class MultiTenantEngine:
     engine holds no per-tenant copy, so engines on one device can share
     the tree. The stacked caches, in the same layout, are placed on the
     device that holds the weights, which is what pins a fleet replica to
-    its chip.
+    its chip. Each space_time step donates the K/V stacks to
+    ``decode_all`` (``donate_caches``), which writes the new token into
+    them in place: ``self.caches`` is replaced on every step, and arrays
+    taken from it before a step are deleted by it.
     """
 
     def __init__(self, model: Model, stacked_params: Any, config: EngineConfig):
@@ -203,7 +248,9 @@ class MultiTenantEngine:
         self._pending_logits: Dict[int, jax.Array] = {}
 
         progs = engine_programs(model, config.cache_len)
-        self._decode_all = progs.decode_all
+        # the K/V caches are donated: self.caches is replaced every step
+        self._kv = frozenset(model.kv_entries())
+        self._decode_all = donate_caches(progs.decode_all, self._kv)
         self._decode_one = progs.decode_one
         self._prefill = progs.prefill
         self._prefill_cont = progs.prefill_cont
@@ -338,8 +385,8 @@ class MultiTenantEngine:
             lengths = jnp.asarray(self._lengths())
             tokens = jnp.asarray(self.last_token)
             logits, self.caches = self._decode_all(
-                self.stacked_params, tokens, self.caches, lengths
-            )
+                self.stacked_params, tokens,
+                *split_kv(self.caches, self._kv), lengths)
             if phases is not None:
                 phases.mark(1)
             self._step_logits = jax.block_until_ready(logits)

@@ -14,6 +14,13 @@ What keeps a request's state out of the next one is admission: the
 engine installs the prefilled cache of the new request over every leaf
 of the slot (``MultiTenantEngine._scatter_slot``) before the slot
 decodes again.
+
+Keys and values are stored positions-last, ``(..., slot, Hkv, D, S)``
+(``models.attention``), and the decode step reads them where they lie
+and writes each slot's new token at position ``length % S`` after the
+layer scan. The engine donates the K/V stacks to that step, so the
+arrays it held before a step are deleted by it: read ``engine.caches``
+anew after each step, never a reference kept from before.
 """
 
 from __future__ import annotations

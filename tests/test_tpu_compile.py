@@ -14,6 +14,8 @@ the jnp reference (this process sees the CPU), the fixture steers
 ``repro.kernels.ops`` to the Pallas kernels itself.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -105,14 +107,29 @@ def test_flash_attention_stablelm(chip, seq):
 
 
 def test_decode_attention_tenant_vmapped(chip):
+    """The decode kernel under the engine's tenant vmap, as the decode
+    step calls it: the stacks passed whole, the tenant index mapped. The
+    mapped axis folds into the kernel's rows, so it is one kernel call
+    and no loop over tenants."""
     from repro.kernels import ops
 
     R, B, H, D, S = 2, 2, STABLELM["heads"], STABLELM["head_dim"], 1024
-    _compile(jax.vmap(ops.decode_attention),
-             chip((R, B, H, D), jnp.bfloat16),
-             chip((R, B, H, S, D), jnp.bfloat16),
-             chip((R, B, H, S, D), jnp.bfloat16),
-             chip((R, B), jnp.int32))
+
+    def step(q, k_new, v_new, k_stack, v_stack, lengths):
+        return jax.vmap(
+            lambda q, kn, vn, n, t: ops.decode_attention(
+                q, kn, vn, k_stack, v_stack, n, 0, t))(
+                    q, k_new, v_new, lengths, jnp.arange(R))
+
+    compiled = _compile(step, chip((R, B, H, D), jnp.bfloat16),
+                        chip((R, B, H, D), jnp.bfloat16),
+                        chip((R, B, H, D), jnp.bfloat16),
+                        chip((1, R, B, H, D, S), jnp.bfloat16),
+                        chip((1, R, B, H, D, S), jnp.bfloat16),
+                        chip((R, B), jnp.int32))
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert " while(" not in text
 
 
 def test_wkv6_scan_rwkv6_widths(chip):
@@ -204,6 +221,58 @@ def test_granite_hybrid_step_fits_one_chip(chip, program):
         f"{program}: temporaries {mem.temp_size_in_bytes / 1e9:.2f} GB")
 
 
+#: (arch, cache length) of the served decode steps: the stablelm cells'
+#: caches (chat 512 + 32 + 8, longprompt 2048 + 4 + 8) and the granite
+#: rag cell's (2048 + 32 + 8)
+IN_PLACE_CASES = {"stablelm-1.6b-552": ("stablelm-1.6b", 552),
+                  "stablelm-1.6b-2060": ("stablelm-1.6b", 2060),
+                  "granite-4.0-h-micro-2088": ("granite-4.0-h-micro", 2088)}
+_HLO_BYTES = {"bf16": 2, "f16": 2, "f32": 4, "s32": 4, "u32": 4, "pred": 1,
+              "s8": 1, "u8": 1}
+
+
+def _copied_bytes(text):
+    """(name, bytes) of every copy and pad in an HLO module's text."""
+    for m in re.finditer(r"%(\S+) = (\w+)\[([\d,]*)\]\S* (copy|copy-start|pad)\(",
+                         text):
+        dims = [int(d) for d in m.group(3).split(",") if d]
+        yield m.group(1), int(np.prod(dims)) * _HLO_BYTES.get(m.group(2), 4)
+
+
+@pytest.mark.parametrize("case", sorted(IN_PLACE_CASES))
+def test_decode_all_reads_and_writes_the_caches_in_place(chip, case):
+    """The engine's donated decode step at full width (R=2 tenants of 2
+    slots, bf16) for a described v5e: no copy or pad in the compiled
+    program yields as many bytes as one layer's K (or V) of one tenant,
+    and the K/V caches are aliased to the step's outputs. A kernel that reads
+    a layout other than the stored one, a layer sliced out for it, a pad
+    of its S or a position written inside the layer scan each copy a
+    layer's K and V on every step."""
+    from repro.config import get_config
+    from repro.models import build_model
+    from repro.serving.engine import donate_caches, engine_programs, split_kv
+
+    arch, cache_len = IN_PLACE_CASES[case]
+    R, B = 2, 2
+    model = build_model(get_config(arch))
+    as_chip = lambda t: jax.tree.map(lambda s: chip(s.shape, s.dtype), t)
+    params, caches = _stacked_shapes(model, R, B, cache_len)
+    kv = frozenset(model.kv_entries())
+    decode_all = donate_caches(engine_programs(model, cache_len).decode_all, kv)
+    compiled = decode_all.lower(
+        as_chip(params), chip((R, B), jnp.int32),
+        *split_kv(as_chip(caches), kv), chip((R, B), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text, "no Pallas kernel"
+    cfg = model.cfg
+    layer_kv = B * cfg.num_kv_heads * cfg.head_dim * cache_len * 2
+    copies = [(name, n) for name, n in _copied_bytes(text) if n >= layer_kv]
+    assert not copies, copies
+    kv_bytes = sum(x.size * x.dtype.itemsize
+                   for x in jax.tree.leaves(split_kv(caches, kv)[0]))
+    assert compiled.memory_analysis().alias_size_in_bytes >= kv_bytes
+
+
 def _stacked_shapes(model, r, b, cache_len):
     """Shapes of the engine's stacked weights and caches (nothing drawn)."""
     from repro.core.tenancy import broadcast_tenants, init_stacked
@@ -244,3 +313,60 @@ def test_decode_all_moves_no_unit_leaf(arch):
     moved = [e for e in body.eqns if e.primitive.name == "transpose"
              and unit & set(e.invars + e.outvars)]
     assert not moved, moved[:3]
+
+
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs inside it."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+
+    for e in jaxpr.eqns:
+        yield e
+        for v in e.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else [v]:
+                if isinstance(sub, ClosedJaxpr):
+                    yield from _eqns(sub.jaxpr)
+                elif isinstance(sub, Jaxpr):
+                    yield from _eqns(sub)
+
+
+@pytest.mark.parametrize("arch", ["stablelm-1.6b", "granite-4.0-h-micro"])
+def test_decode_all_pads_no_cache_and_donates(arch, monkeypatch):
+    """Where no TPU compiler can be described, the guard above on the
+    CPU: traced with the Pallas decode kernel, ``decode_all``'s jaxpr pads
+    nothing as large as one layer's K of one tenant (the kernel reads the
+    stored S, 552 here, unpadded), and the engine's decode call donates
+    its K/V caches: the stacks a step was given are deleted after it,
+    and a recurrent layer's state (granite's Mamba2 layers) is not."""
+    from repro.config import get_config
+    from repro.kernels import ops
+    from repro.models import build_model
+    from repro.serving import EngineConfig, InferenceRequest, MultiTenantEngine
+    from repro.serving.engine import engine_programs, split_kv
+
+    R, B, cache_len = 2, 2, 552
+    model = build_model(get_config(f"{arch}-smoke"))
+    params, caches = _stacked_shapes(model, R, B, cache_len)
+    ids = jax.ShapeDtypeStruct((R, B), jnp.int32)
+    monkeypatch.setattr(ops, "_use_pallas", lambda force_pallas: True)
+    monkeypatch.setattr(ops, "_interpret", lambda: True)
+    fresh = engine_programs.__wrapped__(model, cache_len)  # traced anew
+    jaxpr = jax.make_jaxpr(fresh.decode_all)(params, ids, caches, ids)
+    monkeypatch.setattr(ops, "_use_pallas", lambda force_pallas: False)
+    cfg = model.cfg
+    layer = B * cfg.num_kv_heads * cfg.head_dim * cache_len
+    pads = [e for e in _eqns(jaxpr.jaxpr) if e.primitive.name == "pad"
+            and e.invars[0].aval.size >= layer]
+    assert not pads, pads[:2]
+
+    from repro.core.tenancy import init_stacked
+    eng = MultiTenantEngine(
+        model, init_stacked(model.init, jax.random.PRNGKey(0), R),
+        EngineConfig(num_tenants=R, slots_per_tenant=B, cache_len=32))
+    eng.submit(InferenceRequest(tenant_id=1, prompt=[3, 4, 5],
+                                max_new_tokens=4))
+    eng.step()
+    kv, state = split_kv(eng.caches, frozenset(model.kv_entries()))
+    eng.step()
+    assert all(x.is_deleted() for x in jax.tree.leaves(kv))
+    assert not any(x.is_deleted() for x in jax.tree.leaves(state))
+    assert not any(x.is_deleted() for x in jax.tree.leaves(eng.caches))
