@@ -9,9 +9,10 @@ the queueing layer.
 Two measurements:
 
 (a) engine modes — per-tenant mean step latency spread under the engine's
-    time_only mode (each tenant's decode cohort dispatched as its own
-    bucket through the shared scheduler — spread reflects dispatch order)
-    vs space_time mode (one merged dispatch).
+    time_only mode (each tenant's decode run as its own program, one
+    after another — spread reflects dispatch order) vs space_time mode
+    (one merged dispatch). Each tenant's history also holds its prefill,
+    which waits for the prefills ahead of it in the admission pass.
 
 (b) batching-window policies — the SAME Poisson kernel-arrival trace
     replayed on a deterministic VirtualClock against the fixed window and
@@ -100,8 +101,8 @@ def run(r: int = 5, steps: int = 16, csv_rows=None):
             m, params,
             EngineConfig(num_tenants=r, slots_per_tenant=1, cache_len=64, mode=mode),
         )
-        # per-tenant latency accounting happens inside the shared
-        # scheduler core that both modes route their cohorts through.
+        # the engine records each tenant's prefill and decode latencies
+        # in its own monitor, in both modes
         for t in range(r):
             eng.submit(InferenceRequest(
                 tenant_id=t, prompt=list(rng.randint(1, cfg.vocab_size, 8)),

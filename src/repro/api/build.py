@@ -457,8 +457,8 @@ class LiveRun:
         model = build_model(cfg)
         key = jax.random.PRNGKey(w.seed)
         # the engine's contrast mode mirrors the cost-model strategy:
-        # time_only gives each tenant its own bucket (sequential
-        # dispatch), everything else rides the merged space-time path
+        # time_only decodes each tenant as its own program in turn,
+        # everything else rides the merged space-time path
         engine_mode = ("time_only" if spec.cost_model.strategy == "time_only"
                        else "space_time")
         # one stacked weight tree per device, shared by every replica
@@ -480,10 +480,6 @@ class LiveRun:
                 cache_len=max(32, w.prompt_tokens + w.max_new_tokens + 8),
                 mode=engine_mode,
                 seed=w.seed + i,
-                # admission and batching are the fleet pump's, under the
-                # spec's scheduler; the engine's own core keeps its greedy
-                # default (a feasibility policy there has no cost model)
-                schedule=None,
             ))
             if recorder is not None:
                 engine.recorder = recorder.shard(i)

@@ -2,9 +2,9 @@
 
 Interactive inference queries arrive stochastically; each query decomposes
 into schedulable workloads — kernel launches (mostly GEMMs) at the bottom
-layer, prefill/decode cohorts at the serving layer. The queue groups
-pending workloads by their *bucket* (any hashable mergeability key —
-``ShapeBucket`` for GEMMs, tuples for engine cohorts); items in the same
+layer, requests at the serving layer. The queue groups pending workloads
+by their *bucket* (any hashable mergeability key — ``ShapeBucket`` for
+GEMMs, tuples for requests); items in the same
 bucket are mergeable into one super-dispatch. This is the front-end of the
 unified space-time scheduler.
 """

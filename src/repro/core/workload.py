@@ -3,8 +3,8 @@
 The paper's claim is that ONE dynamic scheduler can merge concurrent work
 from disjoint tenants while preserving latency predictability. For that
 to hold across layers, kernel-level work (a single GEMM) and
-request-level work (a prefill, a tenant's decode step) must flow through
-the SAME policy core. ``Workload`` is that common currency: anything
+request-level work (a served request, or the simulator's prefill and
+decode items) must flow through the SAME policy core. ``Workload`` is that common currency: anything
 with a mergeability bucket, a cost estimate, a tenant, an SLO, and a way
 to execute a batch of its peers.
 
@@ -15,7 +15,7 @@ properties, ``Workload`` via plain fields):
     bucket           : Hashable — items sharing a bucket may be merged
                        into one super-dispatch
     cost             : float — abstract work estimate (FLOPs for GEMMs,
-                       tokens for engine cohorts); feeds throughput stats
+                       tokens for request-level work); feeds throughput stats
                        and virtual-clock cost models
     slo_s            : float — latency objective, drives the adaptive
                        batching window and violation accounting
@@ -57,8 +57,7 @@ def round_pow2(n: int) -> int:
 class Workload:
     """Concrete generic work item (see module docstring for the protocol).
 
-    Layers above the kernel queue (the serving engine's prefill/decode
-    cohorts, future async dispatch) build these directly; the ``execute``
+    Callers above the kernel queue build these directly; the ``execute``
     callback receives the whole merged batch so one callback invocation
     can run one super-dispatch for many tenants.
     """
@@ -72,7 +71,7 @@ class Workload:
     payload: Any = None
     # workload class for per-kind latency percentiles in the monitor
     # (e.g. "prefill" vs "decode" — compile-heavy prefills would otherwise
-    # pollute decode-step p95s in engine reports)
+    # pollute decode-step p95s)
     kind: str = "default"
     arrival_time: float = 0.0
     seq: int = dataclasses.field(default_factory=lambda: next(_seq))

@@ -3,10 +3,10 @@
 The model-level embodiment of space-time scheduling: R tenants of one
 architecture run as ONE vmapped program over stacked weights/caches
 (every layer's GEMMs become inter-model batched super-kernels), with a
-slot-based continuous batcher feeding the decode loop. Prefill and
-decode cohorts are submitted as generic ``Workload`` items through the
-shared ``DynamicSpaceTimeScheduler`` core, which owns admission control,
-per-tenant SLO/latency tracking, and straggler eviction.
+slot-based continuous batcher feeding the decode loop. The engine
+schedules nothing: in a fleet, the pump's space-time scheduler
+(``core.scheduler``) decides when and where a cohort runs, and the engine runs its prefills
+and decode steps to completion, recording each tenant's latency.
 
 ``repro.serving.fleet`` puts N engines behind the sim routers (the live
 half of the fleet story). The engine (and therefore jax) is imported

@@ -1,23 +1,24 @@
-"""Multi-tenant inference engine: space-time scheduled decode loop.
+"""Multi-tenant inference engine: the decode loop of one served replica.
 
 R tenants of the same architecture (different weights) are served by ONE
 jitted, tenant-vmapped decode step over stacked params + stacked caches —
 every projection/FFN GEMM in the model becomes an inter-model batched
 super-kernel, which is the paper's mechanism applied to whole models.
 
-All work flows through the shared ``DynamicSpaceTimeScheduler``: each
-admitted prefill and each tenant's decode step is submitted as a generic
-``Workload`` (bucket, cost, SLO, execute-callback) and dispatched by the
-scheduler's pump. The engine therefore inherits admission control,
-per-tenant SLO/latency tracking, and straggler eviction from the core
-instead of duplicating its own monitor plumbing.
+The engine schedules nothing. In a fleet the pump's space-time
+scheduler (``core.scheduler``) decides when and where a cohort runs
+(``serving.fleet.EngineReplica``); the engine runs it to completion: it
+prefills each queued request that has a free slot, then decodes every
+active slot, one step at a time, until the cohort drains. It records
+each prefill's and each tenant's decode latency in its own
+``LatencyMonitor`` (``monitor``), which ``report`` reads.
 
 ``mode="time_only"`` provides the contrast case: each tenant's decode
-cohort gets its OWN bucket, so the scheduler dispatches them sequentially
-(one program per tenant per step), modeling CUDA context time-slicing —
-a tenant's recorded latency then includes waiting for every tenant ahead
-of it in the dispatch order (the paper's linear-slowdown mechanism).
-Used by benchmarks/fig3_latency.py and fig4_predictability.py.
+runs as its OWN program, one after another with a device sync between
+them (one program per tenant per step), modeling CUDA context
+time-slicing — a tenant's recorded latency then includes waiting for
+every tenant ahead of it (the paper's linear-slowdown mechanism). Used
+by benchmarks/fig3_latency.py and fig4_predictability.py.
 """
 
 from __future__ import annotations
@@ -31,12 +32,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.config import ScheduleConfig
-from repro.core.scheduler import DynamicSpaceTimeScheduler
+from repro.core.slo import LatencyMonitor
 from repro.core.tenancy import (
     broadcast_tenants, map_tenant_axis, stack_params, take_tenant,
     tenant_axes, tenant_counts)
-from repro.core.workload import Workload
 from repro.models import Model
 from repro.obs.recorder import STEP_STAMPS
 from repro.serving.kv_cache import SlotManager
@@ -184,11 +183,6 @@ class EngineConfig:
     prefill_chunk: int = 0
     sampling: SamplingParams = dataclasses.field(default_factory=SamplingParams)
     seed: int = 0
-    ewma_alpha: float = 0.2
-    eviction_ratio: float = 10.0    # effectively off unless benchmarking isolation
-    # optional override for the shared scheduler core (batching policy,
-    # admission caps, ...); None derives one from the fields above.
-    schedule: Optional[ScheduleConfig] = None
 
 
 class MultiTenantEngine:
@@ -220,16 +214,8 @@ class MultiTenantEngine:
         device = next(iter(jax.tree.leaves(stacked_params)[0].devices()))
         self.caches = jax.device_put(broadcast_tenants(single, R), device)
         self.slots = SlotManager(R, B)
-
-        # the unified scheduling core: prefill + decode cohorts flow
-        # through it as Workloads; it owns latency/SLO tracking.
-        schedule = config.schedule or ScheduleConfig(
-            batching_window_s=0.0,
-            max_superkernel_size=max(128, config.num_tenants),
-            latency_ewma_alpha=config.ewma_alpha,
-            straggler_eviction_ratio=config.eviction_ratio,
-        )
-        self.scheduler = DynamicSpaceTimeScheduler(schedule)
+        # per-tenant prefill and decode latency, against each request's SLO
+        self.monitor = LatencyMonitor()
 
         self.queue: List[InferenceRequest] = []
         self.active: Dict[tuple, InferenceRequest] = {}  # (tenant, slot) -> req
@@ -243,7 +229,6 @@ class MultiTenantEngine:
         self.decode_tokens = 0
         self._sample_key = jax.random.PRNGKey(config.seed)
         self._step_logits: Optional[jax.Array] = None  # (R, B, V)
-        self._cohort_step = -1                         # last step decoded merged
         self._pending_caches: Dict[int, Any] = {}      # time_only per-tenant updates
         self._pending_logits: Dict[int, jax.Array] = {}
 
@@ -255,12 +240,6 @@ class MultiTenantEngine:
         self._prefill = progs.prefill
         self._prefill_cont = progs.prefill_cont
 
-    # ---------------------------------------------------------------- monitor
-    @property
-    def monitor(self):
-        """Per-tenant latency/SLO tracking lives in the shared core."""
-        return self.scheduler.monitor
-
     # ------------------------------------------------------------------ intake
     def submit(self, req: InferenceRequest, now: Optional[float] = None) -> None:
         req.arrival_time = now if now is not None else time.perf_counter()
@@ -269,14 +248,17 @@ class MultiTenantEngine:
 
     # ------------------------------------------------------------------ prefill
     def _admit(self) -> None:
-        # Prefill runs at EXACT prompt length (one compile per distinct
-        # length). Padding would corrupt SSM/RWKV recurrent state; callers
-        # wanting fewer compiles should bucket their prompt lengths.
-        # Each admitted prefill is a Workload bucketed by prompt length so
-        # the scheduler accounts its latency per tenant.
+        """Give each queued request whose tenant has a free slot that slot,
+        in queue order, then prefill them in the same order: install each
+        prompt's cache into the stacked cohort and activate its decode
+        slot. A request whose tenant has no free slot stays queued.
+
+        Prefill runs at EXACT prompt length (one compile per distinct
+        length). Padding would corrupt SSM/RWKV recurrent state; callers
+        wanting fewer compiles should bucket their prompt lengths."""
         rec = self.recorder
-        remaining = []
-        submitted = False
+        start = time.perf_counter()
+        admitted, remaining = [], []
         for req in self.queue:
             slot = self.slots.acquire(req.tenant_id, req.request_id)
             if slot is None:
@@ -286,33 +268,9 @@ class MultiTenantEngine:
             if rec is not None:
                 req.slot_time = time.perf_counter()
             req.state = RequestState.PREFILLING
-            ok = self.scheduler.submit(Workload(
-                tenant_id=req.tenant_id,
-                bucket=("prefill", len(req.prompt)),
-                cost=float(len(req.prompt)),
-                slo_s=req.slo_s,
-                execute=self._execute_prefill_batch,
-                payload=req,
-                kind="prefill",
-            ))
-            if not ok:
-                # admission control pushed back: return the slot, retry later
-                self.slots.release(req.tenant_id, slot)
-                req.slot = None
-                req.state = RequestState.QUEUED
-                remaining.append(req)
-                continue
-            submitted = True
+            admitted.append(req)
         self.queue = remaining
-        if submitted:
-            self.scheduler.flush()
-
-    def _execute_prefill_batch(self, batch: List[Workload]) -> List[int]:
-        """Scheduler executor: prefill each admitted request, install its
-        cache into the stacked cohort, and activate its decode slot."""
-        outs = []
-        for wl in batch:
-            req: InferenceRequest = wl.payload
+        for req in admitted:
             tokens = jnp.asarray(np.asarray(req.prompt, np.int32))[None, :]
             logits, cache = self._run_prefill(req.tenant_id, tokens)
             tok, value = jax.device_get(_greedy(logits[0]))
@@ -325,8 +283,8 @@ class MultiTenantEngine:
             self.last_token[req.tenant_id, req.slot] = tok
             req.state = RequestState.DECODING
             self.active[(req.tenant_id, req.slot)] = req
-            outs.append(tok)
-        return outs
+            self.monitor.record(req.tenant_id, req.first_token_time - start,
+                                req.slo_s, kind="prefill")
 
     def _run_prefill(self, tenant: int, tokens):
         """Whole-prompt or chunked prefill (bounded compile count)."""
@@ -373,51 +331,39 @@ class MultiTenantEngine:
             out[t] = self.slots.lengths(t)
         return out
 
-    def _execute_decode_cohort(self, batch: List[Workload]) -> List[jax.Array]:
-        """space_time executor: ONE tenant-vmapped program for the whole
-        cohort — every active tenant in the batch shares the dispatch.
+    def _decode_cohort(self) -> None:
+        """space_time: ONE tenant-vmapped program for the whole cohort —
+        every active tenant shares the dispatch and its completion."""
+        phases = self._phases
+        lengths = jnp.asarray(self._lengths())
+        tokens = jnp.asarray(self.last_token)
+        logits, self.caches = self._decode_all(
+            self.stacked_params, tokens,
+            *split_kv(self.caches, self._kv), lengths)
+        if phases is not None:
+            phases.mark(1)
+        self._step_logits = jax.block_until_ready(logits)
+        if phases is not None:
+            phases.mark(2)
 
-        The decode runs exactly once per engine step even if the scheduler
-        splits the cohort's workloads across pump batches (caches must
-        advance once); later sub-batches reuse the same step's logits."""
-        if self._cohort_step != self.steps:
-            phases = self._phases
-            lengths = jnp.asarray(self._lengths())
-            tokens = jnp.asarray(self.last_token)
-            logits, self.caches = self._decode_all(
-                self.stacked_params, tokens,
-                *split_kv(self.caches, self._kv), lengths)
-            if phases is not None:
-                phases.mark(1)
-            self._step_logits = jax.block_until_ready(logits)
-            if phases is not None:
-                phases.mark(2)
-            self._cohort_step = self.steps
-        return [self._step_logits[wl.payload] for wl in batch]
-
-    def _execute_decode_tenant(self, batch: List[Workload]) -> List[jax.Array]:
-        """time_only executor: a per-tenant program with a device sync per
-        dispatch (the CUDA context-switch analogue). Cache/logit updates
-        are staged and scattered into the stacked trees once per step.
-        A recorded step's ``step.enqueue`` is its first tenant's program
+    def _decode_tenant(self, t: int) -> None:
+        """time_only: tenant ``t``'s own program, with a device sync after
+        it (the CUDA context-switch analogue). Cache/logit updates are
+        staged and scattered into the stacked trees once per step. A
+        recorded step's ``step.enqueue`` is its first tenant's program
         returning, its ``step.ready`` the last tenant's sync."""
         phases = self._phases
-        outs = []
-        for wl in batch:
-            t = wl.payload
-            tokens_t = jnp.asarray(self.last_token[t])
-            lengths_t = jnp.asarray(self.slots.lengths(t), jnp.int32)
-            lg, nc = self._decode_one(self.stacked_params, self.caches,
-                                      np.int32(t), tokens_t, lengths_t)
-            if phases is not None and phases.i < 1:
-                phases.mark(1)
-            lg = jax.block_until_ready(lg)
-            if phases is not None:
-                phases.mark(2)
-            self._pending_caches[t] = nc
-            self._pending_logits[t] = lg
-            outs.append(lg)
-        return outs
+        tokens_t = jnp.asarray(self.last_token[t])
+        lengths_t = jnp.asarray(self.slots.lengths(t), jnp.int32)
+        lg, nc = self._decode_one(self.stacked_params, self.caches,
+                                  np.int32(t), tokens_t, lengths_t)
+        if phases is not None and phases.i < 1:
+            phases.mark(1)
+        lg = jax.block_until_ready(lg)
+        if phases is not None:
+            phases.mark(2)
+        self._pending_caches[t] = nc
+        self._pending_logits[t] = lg
 
     def _apply_pending_tenant_updates(self) -> None:
         """Scatter time_only per-tenant cache/logit updates in one pass."""
@@ -441,12 +387,12 @@ class MultiTenantEngine:
     def step(self) -> int:
         """One engine iteration: admit + one decode step. Returns #tokens.
 
-        The decode cohort is submitted to the shared scheduler as one
-        Workload per active tenant. In space_time mode they share one
-        bucket (one merged dispatch — identical completion time for every
-        tenant, predictability by construction); in time_only mode each
-        tenant gets its own bucket and the scheduler dispatches them
-        sequentially.
+        In space_time mode every active tenant decodes in one merged
+        dispatch (identical completion time for every tenant,
+        predictability by construction); in time_only mode the tenants
+        decode one after another in ascending order. Each active tenant's
+        decode latency (its dispatch's completion minus the step's start)
+        is recorded against the tightest SLO of its active requests.
 
         With a recorder attached the step writes one row of
         ``STEP_STAMPS``; it starts after admission, so prefill is not in
@@ -459,31 +405,22 @@ class MultiTenantEngine:
         phases = self._phases = None if rec is None else _StepPhases()
 
         slo_by_tenant: Dict[int, float] = {}
-        slots_by_tenant: Dict[int, int] = {}
         for (t, _), req in self.active.items():
             slo_by_tenant[t] = min(slo_by_tenant.get(t, float("inf")), req.slo_s)
-            slots_by_tenant[t] = slots_by_tenant.get(t, 0) + 1
-        for t in sorted(slots_by_tenant):
-            merged = self.cfg.mode == "space_time"
-            ok = self.scheduler.submit(Workload(
-                tenant_id=t,
-                bucket=("decode", "cohort") if merged else ("decode", t),
-                cost=float(slots_by_tenant[t]),
-                slo_s=slo_by_tenant[t],
-                execute=(self._execute_decode_cohort if merged
-                         else self._execute_decode_tenant),
-                payload=t,
-                kind="decode",
-            ))
-            if not ok:
-                # a dropped decode workload would silently desync caches
-                raise RuntimeError(
-                    "decode workload rejected by scheduler admission control; "
-                    "max_pending_per_tenant must admit one decode workload "
-                    "per tenant per step"
-                )
-        self.scheduler.flush()
-        self._apply_pending_tenant_updates()
+        tenants = sorted(slo_by_tenant)
+        start = time.perf_counter()
+        if self.cfg.mode == "space_time":
+            self._decode_cohort()
+            done = [time.perf_counter()] * len(tenants)
+        else:
+            done = []
+            for t in tenants:
+                self._decode_tenant(t)
+                done.append(time.perf_counter())
+            self._apply_pending_tenant_updates()
+        for t, t_done in zip(tenants, done):
+            self.monitor.record(t, t_done - start, slo_by_tenant[t],
+                                kind="decode")
         logits = self._step_logits
 
         if self.cfg.sampling.greedy:
@@ -514,7 +451,7 @@ class MultiTenantEngine:
         self.decode_tokens += produced
         if phases is not None:
             phases.mark(4)
-            rec.record_step(phases.t, produced, len(slots_by_tenant))
+            rec.record_step(phases.t, produced, len(tenants))
             self._phases = None
         return produced
 
@@ -532,7 +469,6 @@ class MultiTenantEngine:
             "decode_tokens": float(self.decode_tokens),
             "finished": float(len(self.finished)),
             "slot_utilization": self.slots.utilization(),
-            "scheduler_dispatches": float(self.scheduler.stats.dispatches),
         }
         rep.update(self.monitor.summary())
         # decode-step semantics for the headline percentiles: prefill

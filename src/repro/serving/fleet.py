@@ -2,10 +2,11 @@
 
 The serving half of the fleet story, on the SAME pump/router core the
 simulator runs (``repro.core.pump.PumpCore``): each replica is one
-``PumpCore`` — the real ``DynamicSpaceTimeScheduler`` with the ripeness
-calendar, feasibility admission, EDF drain and preemption — except its
-clock is the WALL clock and its dispatched batches execute on a real
-engine instead of a no-op. Routers (``repro.sim.router``) are pure
+``PumpCore`` — the real space-time scheduler (``core.scheduler``), the
+only scheduler on the served path, with the ripeness calendar,
+feasibility admission, EDF drain and preemption — except its clock is
+the WALL clock and its dispatched batches execute on a real engine
+instead of a no-op. Routers (``repro.sim.router``) are pure
 functions of the pump signals (``queue_depth`` / ``backlog_s`` /
 ``estimate_item_s``), so round_robin / jsq / least_cost / affinity work
 against real execution unchanged, and ``least_cost`` prices through
@@ -152,11 +153,12 @@ class FakeEngine:
 
 
 class EngineReplica:
-    """One real ``MultiTenantEngine`` as a fleet replica executor: a
-    dispatched cohort becomes ``InferenceRequest``s submitted to the
-    engine's own slot-based continuous batcher and drained to completion
-    — the fleet's space-time scheduler decides WHEN and WHERE a cohort
-    runs, the engine decides HOW it packs onto the chip."""
+    """One real ``MultiTenantEngine`` as a fleet replica executor. The
+    fleet pump's space-time scheduler, the only scheduler on the served
+    path, decides WHEN and WHERE a cohort runs; this turns the dispatched
+    cohort into ``InferenceRequest``s and the engine, which schedules
+    nothing of its own, runs them to completion: prefill into free
+    slots, then merged decode steps until every request is done."""
 
     name = "jax"
 

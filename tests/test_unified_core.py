@@ -1,6 +1,7 @@
 """The unified space-time execution core: generic workloads, injected
 clocks, pluggable batching policies, admission control — and the serving
-engine routing its prefill/decode cohorts through the same scheduler.
+engine, which runs its prefills and decode steps itself and records each
+tenant's latency in its own monitor.
 
 These tests run without hypothesis (the property-based variants live in
 test_scheduler_properties.py)."""
@@ -194,8 +195,8 @@ class TestRaggedMergeReference:
         assert all(o.shape == (16, 8) for o in outs)
 
 
-def _setup_engine(mode, R=2, slots=1, cache_len=32):
-    cfg = dataclasses.replace(smoke_variant(get_config("stablelm-1.6b")),
+def _setup_engine(mode, R=2, slots=1, cache_len=32, arch="stablelm-1.6b"):
+    cfg = dataclasses.replace(smoke_variant(get_config(arch)),
                               dtype="float32")
     m = build_model(cfg)
     key = jax.random.PRNGKey(0)
@@ -205,8 +206,10 @@ def _setup_engine(mode, R=2, slots=1, cache_len=32):
     return cfg, eng
 
 
-class TestEngineThroughScheduler:
+class TestEngineRunsItsOwnSteps:
     def test_prefill_and_decode_route_through_shared_core(self):
+        """Every prefill and every tenant's decode step is recorded in the
+        engine's one monitor, which ``report`` reads."""
         cfg, eng = _setup_engine("space_time")
         rng = np.random.RandomState(0)
         for t in range(2):
@@ -215,26 +218,28 @@ class TestEngineThroughScheduler:
                 max_new_tokens=3))
         eng.run_until_drained()
         assert len(eng.finished) == 2
-        # every prefill + decode step went through the scheduler pump:
-        # both same-length prefills MERGE into one dispatch, plus one
-        # dispatch per decode step
-        assert eng.scheduler.stats.dispatches == 3
-        # the engine has no private monitor: it IS the scheduler's
-        assert eng.monitor is eng.scheduler.monitor
-        rep = eng.report()
-        assert rep["scheduler_dispatches"] == 3.0
+        # one prefill record per request; the two prefills' tokens are the
+        # first of three, so two decode steps follow, each recording both
+        # active tenants
+        assert len(eng.monitor.by_kind["prefill"]) == 2
+        assert eng.steps == 2
+        assert len(eng.monitor.by_kind["decode"]) == 2 * 2
+        assert {t: eng.monitor.tenants[t].count for t in (0, 1)} == {0: 3, 1: 3}
         # headline percentiles keep decode-step semantics; compile-heavy
-        # prefill dispatches are reported under their own keys (no
-        # ordering assertion: wall-clock latencies are load-dependent)
+        # prefills are reported under their own keys (no ordering
+        # assertion: wall-clock latencies are load-dependent)
+        rep = eng.report()
         assert rep["p95_s"] == eng.monitor.summary_for("decode")["p95_s"]
         assert "prefill_p95_s" in rep
+        assert "scheduler_dispatches" not in rep
 
-    def test_space_time_and_time_only_identical_greedy_tokens(self):
+    @pytest.mark.parametrize("arch", ["stablelm-1.6b", "granite-4.0-h-micro"])
+    def test_space_time_and_time_only_identical_greedy_tokens(self, arch):
         rng = np.random.RandomState(7)
         prompts = [list(rng.randint(1, 500, 5)) for _ in range(3)]
         results = {}
         for mode in ("space_time", "time_only"):
-            cfg, eng = _setup_engine(mode, R=2)
+            cfg, eng = _setup_engine(mode, R=2, arch=arch)
             for i, p in enumerate(prompts):
                 eng.submit(InferenceRequest(
                     tenant_id=i % 2, prompt=p, max_new_tokens=4))
@@ -244,55 +249,40 @@ class TestEngineThroughScheduler:
                 for r in eng.finished)
         assert results["space_time"] == results["time_only"]
 
-    def test_cohort_split_by_size_cap_decodes_once_per_step(self):
-        """Even with max_superkernel_size=1 (cohort workloads split across
-        pump batches), caches must advance exactly once per step — tokens
-        stay identical to the unconstrained run."""
-        cfg = dataclasses.replace(smoke_variant(get_config("stablelm-1.6b")),
-                                  dtype="float32")
-        m = build_model(cfg)
-        key = jax.random.PRNGKey(0)
-        params = [m.init(jax.random.fold_in(key, t)) for t in range(2)]
-        rng = np.random.RandomState(5)
-        prompts = [list(rng.randint(1, cfg.vocab_size, 4)) for _ in range(2)]
-        results = {}
-        for name, schedule in (
-            ("default", None),
-            ("split", ScheduleConfig(batching_window_s=0.0, max_superkernel_size=1)),
-        ):
-            eng = MultiTenantEngine(m, stack_params(params), EngineConfig(
-                num_tenants=2, slots_per_tenant=1, cache_len=32,
-                mode="space_time", schedule=schedule))
-            for t, p in enumerate(prompts):
-                eng.submit(InferenceRequest(tenant_id=t, prompt=p, max_new_tokens=4))
-            eng.run_until_drained()
-            results[name] = sorted(
-                (r.tenant_id, tuple(r.generated)) for r in eng.finished)
-        assert results["default"] == results["split"]
+    @pytest.mark.parametrize("mode", ["space_time", "time_only"])
+    def test_one_program_call_per_step_or_per_active_tenant(self, mode):
+        """space_time decodes the whole cohort in one merged program a
+        step; time_only calls the per-tenant program once for each active
+        tenant, and the merged one never."""
+        cfg, eng = _setup_engine(mode, R=3)
+        calls = {"all": 0, "one": []}
+        decode_all, decode_one = eng._decode_all, eng._decode_one
 
-    def test_admission_rejection_requeues_request(self):
-        """A prefill pushed back by admission control must return its slot
-        and retry on a later step — no request may be silently dropped."""
-        cfg, eng_unused = _setup_engine("space_time")  # build model/config once
-        m = eng_unused.model
-        params = eng_unused.stacked_params
-        eng = MultiTenantEngine(m, params, EngineConfig(
-            num_tenants=2, slots_per_tenant=2, cache_len=32, mode="space_time",
-            schedule=ScheduleConfig(batching_window_s=0.0,
-                                    max_pending_per_tenant=1)))
-        rng = np.random.RandomState(9)
-        for _ in range(2):  # two same-tenant requests admitted in one pass
+        def count_all(*args):
+            calls["all"] += 1
+            return decode_all(*args)
+
+        def count_one(params, caches, t, *args):
+            calls["one"].append(int(t))
+            return decode_one(params, caches, t, *args)
+
+        eng._decode_all, eng._decode_one = count_all, count_one
+        rng = np.random.RandomState(3)
+        # tenant 2 leaves after two steps: the later steps have 2 tenants
+        for t, n in ((0, 5), (1, 5), (2, 3)):
             eng.submit(InferenceRequest(
-                tenant_id=0, prompt=list(rng.randint(1, cfg.vocab_size, 4)),
-                max_new_tokens=3))
+                tenant_id=t, prompt=list(rng.randint(1, cfg.vocab_size, 4)),
+                max_new_tokens=n))
         eng.run_until_drained()
-        assert len(eng.finished) == 2
-        assert eng.scheduler.stats.rejected >= 1
-        assert eng.slots.utilization() == 0.0
+        assert eng.steps == 4
+        if mode == "space_time":
+            assert calls == {"all": 4, "one": []}
+        else:
+            assert calls == {"all": 0, "one": [0, 1, 2] * 2 + [0, 1] * 2}
 
     def test_time_only_records_positional_latency_skew(self):
         """Sequential per-tenant dispatch: later tenants wait for earlier
-        ones, so the shared monitor must see a nonzero spread; the merged
+        ones, so the engine's monitor must see a nonzero spread; the merged
         cohort gives everyone the same completion time by construction."""
         cfg, eng = _setup_engine("time_only", R=3)
         rng = np.random.RandomState(1)
